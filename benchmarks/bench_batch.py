@@ -10,6 +10,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
 from repro.core.backproject import bp_subline_symmetry_batch
@@ -53,4 +54,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ memory-capacity gate reproduces the paper's P10 observation.
 
 from __future__ import annotations
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.ct_paper import PROBLEMS
 
 from .common import emit
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

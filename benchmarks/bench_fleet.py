@@ -33,6 +33,7 @@ import os
 import subprocess
 import sys
 
+
 from . import common
 
 _SCRIPT = r"""
@@ -44,6 +45,7 @@ import numpy as np
 import jax, jax.numpy as jnp
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import standard_geometry
 from repro.core.fdk import _build_plan
 from repro.runtime.executor import FleetConfig, PlanExecutor
@@ -53,7 +55,7 @@ geom = standard_geometry(n=n, n_det=max(24, 3 * n // 2), n_proj=16)
 rng = np.random.RandomState(0)
 projs = jnp.asarray(rng.rand(geom.n_proj, geom.nh,
                              geom.nw).astype(np.float32))
-kw = dict(nb=8, interpret=True, tiling=(n // 4, n // 4, geom.nz),
+kw = dict(nb=8, tiling=(n // 4, n // 4, geom.nz),
           memory_budget=None, proj_batch=8, out="host", schedule="step")
 plan = _build_plan(geom, "algorithm1_mp", **kw)
 
@@ -88,6 +90,9 @@ def run(devices: int = 8, n: int = 48):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)
+    # the child forces virtual host devices: pin it to the CPU so it
+    # never contends for an accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(devices), str(n)],
         capture_output=True, text=True, timeout=900, env=env,
@@ -124,4 +129,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

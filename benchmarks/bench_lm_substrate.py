@@ -8,6 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import RunConfig, ShapeConfig, get_smoke_config, \
     list_archs
 from repro.launch.train import init_state, make_train_step
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

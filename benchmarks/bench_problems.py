@@ -12,6 +12,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.ct_paper import PROBLEMS
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

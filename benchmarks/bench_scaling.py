@@ -18,6 +18,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
 from repro.core.backproject import bp_subline_symmetry_batch
@@ -61,4 +62,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -45,6 +45,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
 from repro.core.variants import get_variant
@@ -186,4 +187,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import standard_geometry
 from repro.core.forward import forward_project
 from repro.core.phantom import shepp_logan_3d
@@ -101,4 +102,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -37,6 +37,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import standard_geometry
 from repro.runtime.executor import PlanExecutor, ProgramCache
 from repro.runtime.planner import plan_reconstruction
@@ -147,4 +148,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -30,6 +30,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import standard_geometry
 from repro.runtime import telemetry
 from repro.runtime.executor import PlanExecutor, ProgramCache
@@ -96,4 +97,5 @@ def run(n: int = 24, n_det: int = 32, n_proj: int = 16, nb: int = 4) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -17,6 +17,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
 from repro.core.tiling import tile_working_set_bytes
@@ -102,4 +103,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -19,6 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import projection_matrices, standard_geometry, \
     transpose_projections
 from repro.core.variants import VARIANTS, get_variant
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     from . import (
@@ -51,4 +53,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 import repro
 from repro import ReconOptions
+from repro.compile_cache import enable_compile_cache
 from repro.core import ball_phantom, standard_geometry
 from repro.core.forward import forward_project
 
@@ -76,4 +77,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

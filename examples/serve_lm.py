@@ -6,6 +6,7 @@ batching over a shared decode step (launch/serve.py BatchedServer).
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ModelConfig
 from repro.data import ByteTokenizer
 from repro.launch.serve import BatchedServer, Request
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -22,6 +22,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import fdk_reconstruct, shepp_logan_3d, standard_geometry
 from repro.core.forward import forward_project
 from repro.runtime.service import ReconService
@@ -91,4 +92,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import argparse
 import dataclasses
 import logging
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ModelConfig, RunConfig, ShapeConfig
 from repro.launch.train import train
 
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -52,7 +52,7 @@ class ReconOptions:
     # -- planner-owned -----------------------------------------------------
     variant: str = "algorithm1_mp"
     nb: int = 8
-    interpret: bool = True
+    interpret: Optional[bool] = None      # None: derived from the platform
     tiling: Union[None, str, Sequence[int]] = None
     memory_budget: Optional[int] = None
     proj_batch: Optional[int] = None

@@ -36,14 +36,10 @@ from .tiling import translate_matrices  # noqa: F401  (re-export; moved)
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (replication checks off: the psum over
-    "pod" is the only cross-slab collective and is explicit)."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm  # jax 0.4.x
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    """``jax.shard_map`` with replication checks off: the psum over
+    "pod" is the only cross-slab collective and is explicit."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _pad_up(n: int, k: int) -> int:
@@ -112,7 +108,7 @@ def make_distributed_bp(geom: CTGeometry, mesh, *, nb: int = 32,
 
 def make_fleet_bp(variant: str, call_shape: Tuple[int, int, int], *,
                   nb: int, n_chunks: int, chunk_size: int,
-                  options=(), interpret: bool = True,
+                  options=(), interpret: bool = False,
                   rb: Optional[int] = None):
     """Per-device step program for the reconstruction fleet
     (``runtime.executor.PlanExecutor.execute_fleet``).

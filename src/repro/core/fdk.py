@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from .geometry import CTGeometry, projection_matrices
 
 
-def _build_plan(geom: CTGeometry, variant: str, *, nb: int, interpret: bool,
+def _build_plan(geom: CTGeometry, variant: str, *, nb: int,
+                interpret: Optional[bool],
                 tiling, memory_budget: Optional[int],
                 proj_batch: Optional[int], out: Optional[str],
                 schedule: Optional[str] = None, ingest: str = "offline",
@@ -48,7 +49,7 @@ def _build_plan(geom: CTGeometry, variant: str, *, nb: int, interpret: bool,
 
 def fdk_reconstruct(projections: jnp.ndarray, geom: CTGeometry,
                     variant: str = "algorithm1_mp", *,
-                    nb: int = 8, interpret: bool = True,
+                    nb: int = 8, interpret: Optional[bool] = None,
                     tiling: Union[None, str, Sequence[int]] = None,
                     memory_budget: Optional[int] = None,
                     proj_batch: Optional[int] = None,
@@ -178,7 +179,7 @@ def _vol_to_native(vol_t):
 def sart_step(vol_zyx: jnp.ndarray, projections: jnp.ndarray,
               geom: CTGeometry, *, relax: float = 0.25,
               variant: str = "algorithm1_mp", nb: int = 8,
-              oversample: float = 1.0, interpret: bool = True,
+              oversample: float = 1.0, interpret: Optional[bool] = None,
               tiling: Union[None, str, Sequence[int]] = None,
               memory_budget: Optional[int] = None,
               proj_batch: Optional[int] = None,

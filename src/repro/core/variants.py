@@ -83,7 +83,7 @@ def _subline_batch(img_t, mat, vol_shape_xyz, nb: int = 8, **_):
 
 
 def _subline_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
-                    interpret: bool = True, block=(4, 8),
+                    interpret=None, block=None,
                     proj_loop: bool = False, **_):
     from repro.kernels import ops
     return ops.backproject_subline(img_t, mat, vol_shape_xyz, nb=nb,
@@ -92,7 +92,7 @@ def _subline_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
 
 
 def _onehot_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
-                   interpret: bool = True, block=(4, 8),
+                   interpret=None, block=None,
                    k_chunk: int = 128, proj_loop: bool = False, **_):
     from repro.kernels import ops
     return ops.backproject_onehot(img_t, mat, vol_shape_xyz, nb=nb,
@@ -101,7 +101,7 @@ def _onehot_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
 
 
 def _banded_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
-                   interpret: bool = True, block=(4, 8), bw: int = 32,
+                   interpret=None, block=None, bw: int = 32,
                    proj_loop: bool = False, **_):
     from repro.kernels import ops
     return ops.backproject_banded(img_t, mat, vol_shape_xyz, nb=nb,
@@ -131,7 +131,8 @@ class KernelSpec:
         a Z-slab that is neither volume-centered nor mirror-paired.
         ``None`` for symmetry-free kernels (they are their own fallback).
     backend : "reference" | "jax" | "pallas" (Pallas kernels accept
-        ``interpret=`` and run under the interpreter on CPU CI).
+        ``interpret=``, which the planner derives from the platform:
+        the interpreter on CPU, Mosaic-compiled on TPU).
     jittable : whether the kernel tolerates traced inputs under an outer
         ``jax.jit`` (the program cache wraps jittable kernels; a kernel
         that inspects concrete matrix VALUES at trace time — e.g. the

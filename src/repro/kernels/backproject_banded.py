@@ -31,10 +31,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .backproject_subline import _accumulate_projection, fused_batch_ok
+from .backproject_subline import (backproject_call, fused_batch_ok,
+                                  gather_interp, padded_lanes)
+
+# Band-table entries one kernel call scalar-prefetches (128 KiB of SMEM).
+BAND_TABLE_ENTRIES = 32 * 1024
 
 
 def band_layout(img_t: jnp.ndarray, bw: int):
@@ -88,135 +90,10 @@ def tile_bands(mat: np.ndarray, ni: int, nj: int, BI: int, BJ: int,
     return np.ascontiguousarray(np.transpose(band, (2, 0, 1))), span
 
 
-def _make_kernel(BI: int, BJ: int, nz: int, bw: int, nw: int, nh: int):
-    GJ = BJ // 8
-
-    def kernel(band_ref, mat_ref, img_ref, out_ref, smem_ref):
-        ti = pl.program_id(0)
-        tj = pl.program_id(1)
-        s = pl.program_id(2)
-
-        @pl.when(s == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        col0 = band_ref[s, ti, tj] * bw           # global col of block[0]
-        _accumulate_projection(
-            mat_ref, lambda loc: img_ref[pl.ds(loc, 2), :],
-            out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh,
-            band=(col0, 2 * bw))
-
-    return kernel
-
-
-def _make_fused_kernel(BI: int, BJ: int, nz: int, bw: int, nw: int,
-                       nh: int, nb: int):
-    """Fused multi-batch mode (``proj_loop``): one band block + one
-    (nb, 3, 4) matrix block per grid step, in-kernel ``fori_loop`` over
-    the batch. The band is SHARED by the batch (tile_bands group=nb
-    guarantees the batch's x-range union fits the 2*bw window), so the
-    prefetch engine DMAs one band per nb projections."""
-    GJ = BJ // 8
-
-    def kernel(band_ref, mat_ref, img_ref, out_ref, smem_ref):
-        ti = pl.program_id(0)
-        tj = pl.program_id(1)
-        sb = pl.program_id(2)
-
-        @pl.when(sb == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        col0 = band_ref[sb, ti, tj] * bw          # batch-shared band
-
-        def body(b, carry):
-            _accumulate_projection(
-                mat_ref[b], lambda loc: img_ref[b, pl.ds(loc, 2), :],
-                out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh,
-                band=(col0, 2 * bw))
-            return carry
-
-        jax.lax.fori_loop(0, nb, body, 0)
-
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "bw", "nw", "interpret"),
-)
-def _banded_call(img_b, mat, band, vol_shape_xyz, *, block, bw, nw,
-                 interpret):
-    n_proj = img_b.shape[0]
-    nh = img_b.shape[3]
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    # nw = TRUE detector width: the validity mask must not admit the
-    # zero-padded band tail (cols nw-1..) or edge columns leak into the
-    # interpolation.
-    kernel = _make_kernel(BI, BJ, nz, bw, nw, nh)
-    grid = (ni // BI, nj // BJ, n_proj)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 3, 4), lambda ti, tj, s, band: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, None, 2 * bw, nh),
-                         lambda ti, tj, s, band: (s, band[s, ti, tj],
-                                                  0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz),
-                               lambda ti, tj, s, band: (ti, tj, 0)),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        interpret=interpret,
-    )(band, mat.astype(jnp.float32), img_b.astype(jnp.float32))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "bw", "nw", "nb",
-                     "interpret"),
-)
-def _banded_call_fused(img_b, mat, band, vol_shape_xyz, *, block, bw, nw,
-                       nb, interpret):
-    n_proj = img_b.shape[0]
-    nh = img_b.shape[3]
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    kernel = _make_fused_kernel(BI, BJ, nz, bw, nw, nh, nb)
-    grid = (ni // BI, nj // BJ, n_proj // nb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nb, 3, 4), lambda ti, tj, s, band: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((nb, None, 2 * bw, nh),
-                         lambda ti, tj, s, band: (s, band[s, ti, tj],
-                                                  0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz),
-                               lambda ti, tj, s, band: (ti, tj, 0)),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        interpret=interpret,
-    )(band, mat.astype(jnp.float32), img_b.astype(jnp.float32))
-
-
 def backproject_banded(img_t: jnp.ndarray, mat: jnp.ndarray,
-                       vol_shape_xyz, *, block=(4, 8), bw: int = 32,
+                       vol_shape_xyz, *, block=(8, 32), bw: int = 32,
                        nb: int = 0, proj_loop: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """Banded back-projection. img_t (np, nw, nh); returns (ni, nj, nz).
 
     Picks/validates the band width: requires max tile x-span + 2 <= bw
@@ -225,13 +102,15 @@ def backproject_banded(img_t: jnp.ndarray, mat: jnp.ndarray,
     fused multi-batch kernel runs instead: one band per nb-projection
     batch (the span check covers the batch union — wider motion per
     batch may force a larger bw), 1/nb output read-modify-write traffic.
+    The band (2*bw detector columns) is 8-aligned and at least
+    ``SLAB`` wide, so bw is rounded up to a multiple of 8.
     """
     n_proj, nw, nh = img_t.shape
     ni, nj, nz = vol_shape_xyz
     BI, BJ = block
     assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0
-    fused = fused_batch_ok(n_proj, nb, proj_loop)
-    group = nb if fused else 1
+    group = nb if fused_batch_ok(n_proj, nb, proj_loop) else 1
+    bw = max(8, -(-int(bw) // 8) * 8)
     mat_np = np.asarray(mat)
     while True:
         n_bands = max(1, -(-nw // bw))
@@ -240,10 +119,36 @@ def backproject_banded(img_t: jnp.ndarray, mat: jnp.ndarray,
         if span <= bw or bw >= nw:
             break
         bw *= 2
+    nh_p = padded_lanes(nh)
+    if nh_p != nh:
+        img_t = jnp.pad(img_t, ((0, 0), (0, 0), (0, nh_p - nh)))
     img_b, n_bands = band_layout(img_t, bw)
-    if fused:
-        return _banded_call_fused(
-            img_b, mat, jnp.asarray(band), tuple(vol_shape_xyz),
-            block=block, bw=bw, nw=nw, nb=nb, interpret=interpret)
-    return _banded_call(img_b, mat, jnp.asarray(band), tuple(vol_shape_xyz),
-                        block=block, bw=bw, nw=nw, interpret=interpret)
+    # The band table is scalar-prefetched into SMEM (1 MiB on v5e): run
+    # the projections in runs whose table fits BAND_TABLE_ENTRIES,
+    # accumulating into one aliased volume.
+    n_batches = band.shape[0]
+    per_call = max(1, BAND_TABLE_ENTRIES // (band.shape[1] * band.shape[2]))
+    vol = None
+    for b0 in range(0, n_batches, per_call):
+        b1 = min(b0 + per_call, n_batches)
+        vol = _banded_call(
+            img_b[b0 * group:b1 * group], mat[b0 * group:b1 * group],
+            jnp.asarray(band[b0:b1].reshape(-1)), vol,
+            tuple(vol_shape_xyz), block=block, bw=bw, nw=nw, nh=nh,
+            nb=group, interpret=interpret)
+    return vol
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("vol_shape_xyz", "block", "bw", "nw", "nh", "nb",
+                     "interpret"),
+)
+def _banded_call(img_b, mat, band, acc, vol_shape_xyz, *, block, bw, nw, nh,
+                 nb, interpret):
+    # nw = TRUE detector width: the validity mask must not admit the
+    # zero-padded band tail (cols nw-1..) or edge columns leak into the
+    # interpolation.
+    return backproject_call(img_b, mat, vol_shape_xyz, block=block, nb=nb,
+                            nw=nw, nh=nh, interp=gather_interp(nh),
+                            interpret=interpret, band=band, bw=bw, acc=acc)

@@ -2,21 +2,22 @@
 
 Beyond-paper variant (DESIGN.md §2, assumption change #2). The paper's
 sub-line stage 2 is a per-point gather in the cache-resident sMem buffer —
-cheap on CPUs, but on TPU a dynamic gather along lanes serializes on the
-VPU. This kernel replaces the gather with a *sparse interpolation matrix
-contracted on the MXU*:
+cheap on CPUs, but on TPU a lane gather stays inside one vreg. This
+kernel replaces the gather with a *sparse interpolation matrix contracted
+on the MXU*, one voxel line at a time:
 
-    val[j, k] = sum_n A[j, k, n] * sMem[j, n]
-    A[j, k, n] = (1-dy) * [n == floor(y)] + dy * [n == floor(y)+1]
+    val[k] = sum_n sMem[n] * A[n, k]
+    A[n, k] = (1-dy_k) * [n == floor(y_k)] + dy_k * [n == floor(y_k)+1]
 
 A is built from broadcasted iotas (pure VPU compares, no gathers) and the
-contraction is a batched GEMV on the MXU. The trade: 2*kh*nh FLOPs per
-line instead of ~6*kh gather-ops — profitable when gather throughput,
-not FLOPs, is the bottleneck (roofline arithmetic in EXPERIMENTS.md §Perf
-compares both kernels on the same problem).
+contraction is a (1, nh) x (nh, kw) matmul on the MXU at
+``Precision.HIGHEST`` (float32 accuracy: the 1e-5 RMSE bar must hold on
+the chip). The trade: 2*kw*nh FLOPs per line chunk instead of ~6*kw
+gather-ops — profitable only when gather throughput, not FLOPs, bounds.
 
-Schedule, blocking, hoisting, symmetry and the sub-line stage 1 are
-identical to backproject_subline.py.
+Schedule, blocking, hoisting, symmetry and the sub-line stage 1 are the
+sub-line kernel's (``backproject_subline.backproject_call``); only the
+stage-2 interpolation differs.
 """
 
 from __future__ import annotations
@@ -25,178 +26,54 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .backproject_subline import _stage1_lines, _y_affine
+from .backproject_subline import LANES, _row_coords, backproject_call
 
 
-def _accumulate_projection_onehot(m, img_cols, out_ref, smem_ref, i0, j0,
-                                  BI: int, GJ: int, nz: int, nw: int,
-                                  nh: int, k_chunk: int, n_iota):
-    """Accumulate ONE projection via the MXU one-hot contraction.
+def onehot_interp(nh: int):
+    """Stage 2 by MXU contraction: (8, kw) row coordinates -> values."""
 
-    Shared between the per-projection grid kernel and the fused
-    multi-batch (``proj_loop``) kernel; stage 1 and the y-coefficient
-    hoist are the sub-line kernel's (``_stage1_lines``/``_y_affine``) —
-    only stage 2 (gather -> MXU contraction) differs."""
-    kh = nz // 2          # mirrored half
-    khp = nz - kh         # direct half (includes middle plane for odd nz)
-    for ii in range(BI):
-        i_g = i0 + ii
-        for jg in range(GJ):
-            f_vec, w_vec = _stage1_lines(m, img_cols, smem_ref, i_g, j0,
-                                         jg, nw)
-            a, b = _y_affine(m, i_g, j0, jg, f_vec)
-            sm = smem_ref[...]                              # (8, nh)
+    def interp(sm_ref, y):
+        iyc, dy, ok = _row_coords(y, nh)
+        nh_p, kw = sm_ref.shape[1], y.shape[1]
+        n_iota = jax.lax.broadcasted_iota(jnp.int32, (nh_p, kw), 0)
+        line = jax.lax.broadcasted_iota(jnp.int32, (8, kw), 0)
+        out = jnp.zeros((8, kw), jnp.float32)
+        for l in range(8):
+            i_l = iyc[l:l + 1, :]                          # (1, kw)
+            d_l = dy[l:l + 1, :]
+            a = jnp.where(n_iota == i_l, 1.0 - d_l,
+                          jnp.where(n_iota == i_l + 1, d_l, 0.0))
+            v = jax.lax.dot_general(
+                sm_ref[l:l + 1, :], a,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)         # (1, kw)
+            out = jnp.where(line == l, v, out)
+        return jnp.where(ok, out, 0.0)
 
-            def interp_onehot(yy):
-                """(8, kc) coords -> (8, kc) values via MXU contraction."""
-                y0 = jnp.floor(yy)
-                iy = y0.astype(jnp.int32)
-                dy = yy - y0
-                ok = (iy >= 0) & (iy <= nh - 2)
-                iyc = jnp.clip(iy, 0, nh - 2)
-                lo = (n_iota == iyc[..., None]).astype(jnp.float32)
-                hi = (n_iota == (iyc + 1)[..., None]).astype(jnp.float32)
-                A = lo * (1.0 - dy)[..., None] + hi * dy[..., None]
-                A = A * ok[..., None].astype(jnp.float32)
-                # batched GEMV on the MXU: (8, kc, nh) x (8, nh) -> (8, kc)
-                return jax.lax.dot_general(
-                    A, sm,
-                    dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
-
-            jlo = jg * 8
-            for kc0 in range(0, khp, k_chunk):
-                kc = min(k_chunk, khp - kc0)
-                k = kc0 + jax.lax.broadcasted_iota(
-                    jnp.float32, (8, kc), 1)
-                y = a + b * k
-                lo_v = interp_onehot(y) * w_vec
-                out_ref[ii, jlo:jlo + 8, kc0:kc0 + kc] += lo_v
-                # Mirrored half only covers k < kh (skips the odd-nz
-                # self-mirrored middle plane).
-                kch = max(0, min(kc0 + kc, kh) - kc0)
-                if kch > 0:
-                    hi_v = interp_onehot(
-                        (nh - 1.0) - y[:, :kch]) * w_vec
-                    out_ref[ii, jlo:jlo + 8,
-                            nz - kc0 - kch:nz - kc0] += hi_v[:, ::-1]
-
-
-def _make_kernel(BI: int, BJ: int, nz: int, nw: int, nh: int, k_chunk: int):
-    GJ = BJ // 8
-
-    def kernel(mat_ref, img_ref, out_ref, smem_ref):
-        s = pl.program_id(2)
-        ti = pl.program_id(0)
-        tj = pl.program_id(1)
-
-        @pl.when(s == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        n_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nh), 2)
-        _accumulate_projection_onehot(
-            mat_ref, lambda ixc: img_ref[pl.ds(ixc, 2), :],
-            out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh,
-            k_chunk, n_iota)
-
-    return kernel
-
-
-def _make_fused_kernel(BI: int, BJ: int, nz: int, nw: int, nh: int,
-                       k_chunk: int, nb: int):
-    """Fused multi-batch mode (``proj_loop``): in-kernel ``fori_loop``
-    over the nb projections of one batch block — the Z-slab accumulator
-    is read-modified-written once per batch instead of once per
-    projection (see backproject_subline._make_fused_kernel)."""
-    GJ = BJ // 8
-
-    def kernel(mat_ref, img_ref, out_ref, smem_ref):
-        ti = pl.program_id(0)
-        tj = pl.program_id(1)
-        sb = pl.program_id(2)
-
-        @pl.when(sb == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        n_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nh), 2)
-
-        def body(b, carry):
-            _accumulate_projection_onehot(
-                mat_ref[b], lambda ixc: img_ref[b, pl.ds(ixc, 2), :],
-                out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh,
-                k_chunk, n_iota)
-            return carry
-
-        jax.lax.fori_loop(0, nb, body, 0)
-
-    return kernel
+    return interp
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "k_chunk", "interpret"),
+    static_argnames=("vol_shape_xyz", "block", "k_chunk", "nb", "nw", "nh",
+                     "interpret"),
 )
 def backproject_onehot_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
-                              vol_shape_xyz, *, block=(4, 8),
-                              k_chunk: int = 128,
-                              interpret: bool = True) -> jnp.ndarray:
-    n_proj, nw, nh = img_t.shape
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0
-    k_chunk = min(k_chunk, nz - nz // 2)
-
-    kernel = _make_kernel(BI, BJ, nz, nw, nh, k_chunk)
-    grid = (ni // BI, nj // BJ, n_proj)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 3, 4), lambda ti, tj, s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, nw, nh), lambda ti, tj, s: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz), lambda ti, tj, s: (ti, tj, 0)),
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-        interpret=interpret,
-    )(mat.astype(jnp.float32), img_t.astype(jnp.float32))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "k_chunk", "nb", "interpret"),
-)
-def backproject_onehot_fused(img_t: jnp.ndarray, mat: jnp.ndarray,
-                             vol_shape_xyz, *, block=(4, 8),
-                             k_chunk: int = 128, nb: int = 8,
-                             interpret: bool = True) -> jnp.ndarray:
-    """Fused multi-batch (``proj_loop``) form of the one-hot kernel;
-    requires ``n_proj % nb == 0`` (ops.py falls back otherwise)."""
-    n_proj, nw, nh = img_t.shape
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0
-    assert n_proj % nb == 0 and nb >= 1, (n_proj, nb)
-    k_chunk = min(k_chunk, nz - nz // 2)
-
-    kernel = _make_fused_kernel(BI, BJ, nz, nw, nh, k_chunk, nb)
-    grid = (ni // BI, nj // BJ, n_proj // nb)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nb, 3, 4), lambda ti, tj, s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((nb, nw, nh), lambda ti, tj, s: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz), lambda ti, tj, s: (ti, tj, 0)),
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-        interpret=interpret,
-    )(mat.astype(jnp.float32), img_t.astype(jnp.float32))
+                              vol_shape_xyz, *, block=(8, 32),
+                              k_chunk: int = LANES, nb: int = 1,
+                              nw: int, nh: int,
+                              interpret: bool = False) -> jnp.ndarray:
+    """One-hot kernel on padded projections (see
+    ``backproject_subline_pallas`` for the layout contract); ``k_chunk``
+    is the k-chunk width of one contraction (a multiple of 128 on TPU)."""
+    if not interpret and k_chunk % LANES:
+        raise ValueError(
+            f"onehot_pl: k_chunk={k_chunk} must be a multiple of {LANES} "
+            f"on TPU")
+    nh_p = img_t.shape[-1]
+    return backproject_call(img_t, mat, tuple(vol_shape_xyz), block=block,
+                            nb=nb, nw=nw, nh=nh, interp=onehot_interp(nh),
+                            interpret=interpret, kw=k_chunk,
+                            k_work=4 * 4 * nh_p * k_chunk)

@@ -2,29 +2,43 @@
 
 TPU-native schedule (see DESIGN.md §2 for the CPU->TPU mapping):
 
-  grid = (ni/BI, nj/BJ, np)          # s innermost
-  img block   (nw, nh)   <- indexed by s: streamed through VMEM, Pallas
-                            double-buffers it across grid steps = the
-                            paper's Algorithm 2 prefetch, for free.
-  mat block   (3, 4)     <- SMEM scalars (the 48-byte matrix of §3.2.1-I).
+  grid = (ni/BI, nj/BJ, np/nb)       # projection batches innermost
+  img block   (nb, nw, nh) <- indexed by the batch: streamed through VMEM,
+                            Pallas double-buffers it across grid steps =
+                            the paper's Algorithm 2 prefetch, for free.
+  mat block   (nb, 3, 4) <- SMEM scalars (the 48-byte matrix of §3.2.1-I),
+                            read one scalar at a time.
   out block   (BI,BJ,nz) <- indexed by (ti,tj) only: VMEM-resident across
-                            the whole s sweep (output-stationary), zeroed
-                            at s==0, written back to HBM exactly once.
-                            This is the nb->np limit of the paper's
-                            batching: volume HBM traffic = one write.
+                            the whole projection sweep (output-stationary),
+                            zeroed at the first batch, written back to HBM
+                            exactly once. This is the nb->np limit of the
+                            paper's batching: volume HBM traffic = one write.
   scratch     (8, nh)    <- the sMem sub-line buffer (Fig. 3a) in VMEM.
+
+``nb == 1`` is the per-projection grid; ``nb > 1`` (``proj_loop``) walks
+the batch with an in-kernel ``fori_loop``, so the Z-slab accumulator is
+read-modified-written once per nb projections (paper O5 in the kernel).
 
 Inside each grid cell the voxel lines of the (BI, BJ) tile are processed
 in groups of 8 (TPU sublanes). Per line the k-invariant scalars
 F = 1/z, W = F*F, X (paper lines 4..7) are computed on the scalar core
-from SMEM matrix entries — the hoisting of O2 — and X drives a 2-column
-dynamic slice of the image block whose blend is the sub-line (O4).
-The vertical coordinate y is affine in k, evaluated vectorized over the
-(8, nz/2) half-tile; the mirrored half reuses it via y' = nh-1-y (O3).
+from SMEM matrix entries — the hoisting of O2 — and X selects the two
+detector columns whose blend is the sub-line (O4). The columns are read
+as one 8-aligned 16-row slab and blended by a masked sublane reduction
+(Mosaic loads at dynamic sublane offsets only when they are 8-aligned).
 
-Alignment notes (TPU target): nh and nz should be multiples of 128 and
-BJ a multiple of 8 for native tiling; the wrapper in ops.py pads. CPU
-validation runs the same kernel with interpret=True.
+The vertical coordinate is affine in k, evaluated over 128-lane k chunks.
+O3 is the hoisted mirror intercept of ``core.backproject``: the upper
+half's row is y'(k) = (nh-1) - y(nz-1-k) = a_m + b*k, so both halves are
+one select + FMA and nothing is reversed. Stage 2 interpolates inside the
+sub-line with single-vreg lane gathers (one per 128-row chunk of the
+sub-line, merged by select): the only gather Mosaic lowers on v5e.
+
+Alignment: the wrappers in ops.py pad nw to a multiple of 8 (at least 16)
+and nh to a multiple of 128 with zeros, and pass the TRUE nw/nh for the
+validity masks and the mirror. nz is never padded; a partial last k chunk
+is stored with a lane mask. CPU validation runs the same kernel with
+interpret=True.
 """
 
 from __future__ import annotations
@@ -36,6 +50,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128            # k-chunk width and sub-line gather granule
+SLAB = 16              # detector columns read per line (8-aligned window)
+# Default scoped VMEM of a v5e TensorCore; kernels that need more raise
+# the limit explicitly (the chip has 128 MiB).
+_DEFAULT_VMEM = 16 * 2 ** 20
+_MAX_VMEM = 100 * 2 ** 20
+
 
 def fused_batch_ok(n_proj: int, nb: int, proj_loop: bool) -> bool:
     """Whether the fused multi-batch (``proj_loop``) kernel may run: an
@@ -45,14 +66,59 @@ def fused_batch_ok(n_proj: int, nb: int, proj_loop: bool) -> bool:
     return bool(proj_loop) and nb > 1 and n_proj % nb == 0
 
 
-def _line_scalars(mat_ref, i_g, j_g, nw):
+def _pad_to(n: int, b: int) -> int:
+    return ((n + b - 1) // b) * b
+
+
+def padded_rows(nw: int) -> int:
+    """Detector-column extent of the image block: 8-aligned, >= SLAB."""
+    return max(SLAB, _pad_to(nw, 8))
+
+
+def padded_lanes(nh: int) -> int:
+    """Detector-row extent of the image block: a multiple of 128."""
+    return _pad_to(nh, LANES)
+
+
+def vmem_bytes(block, nz: int, rows: int, nh_p: int, nb: int,
+               k_work: int = 0) -> int:
+    """Modeled VMEM of one kernel instance: double-buffered image and
+    output blocks, the sub-line scratch, and ``k_work`` extra bytes of
+    per-chunk temporaries (the one-hot kernel's interpolation matrix)."""
+    BI, BJ = block
+    img = 2 * nb * rows * nh_p * 4
+    out = 2 * BI * BJ * _pad_to(nz, LANES) * 4
+    return img + out + 8 * nh_p * 4 + SLAB * nh_p * 4 + k_work
+
+
+def compiler_params(vmem: int):
+    """Raise the scoped-VMEM limit only when the model needs it."""
+    if vmem + vmem // 4 <= _DEFAULT_VMEM:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(_MAX_VMEM, vmem + vmem // 4 + 2 ** 20)))
+
+
+class _Mat:
+    """Projection ``b`` of an (nb, 3, 4) SMEM matrix block, read one
+    scalar at a time (``m[r, c]`` loads ``mat_ref[b, r, c]``; Mosaic
+    loads only scalars from SMEM)."""
+
+    def __init__(self, ref, b):
+        self.ref, self.b = ref, b
+
+    def __getitem__(self, rc):
+        return self.ref[self.b, rc[0], rc[1]]
+
+
+def _line_scalars(m, i_g, j_g, nw):
     """Scalar-core computation of z, F, W, X, x-column and blend weight
     for one voxel line (i_g, j_g). Everything here is k-invariant (O2)."""
     i_f = i_g.astype(jnp.float32)
     j_f = j_g.astype(jnp.float32)
-    z = mat_ref[2, 0] * i_f + mat_ref[2, 1] * j_f + mat_ref[2, 3]
+    z = m[2, 0] * i_f + m[2, 1] * j_f + m[2, 3]
     f = 1.0 / z
-    x = (mat_ref[0, 0] * i_f + mat_ref[0, 1] * j_f + mat_ref[0, 3]) * f
+    x = (m[0, 0] * i_f + m[0, 1] * j_f + m[0, 3]) * f
     x0 = jnp.floor(x)
     ix = x0.astype(jnp.int32)
     dx = x - x0
@@ -64,21 +130,23 @@ def _line_scalars(mat_ref, i_g, j_g, nw):
     return f, w_eff, ixc, dx
 
 
-def _stage1_lines(m, img_cols, smem_ref, i_g, j0, jg, nw, band=None):
+def _stage1_lines(m, img, smem_ref, i_g, j_base, nw, band=None):
     """Stage 1 for one 8-line group (O4, Fig. 3a): blend the two
     detector columns of each line into the sMem scratch; returns the
     (8, 1) ``f`` and effective-weight vectors.
 
-    ``m`` is the 3x4 matrix (SMEM ref or loaded array — both
-    scalar-indexable); ``img_cols(ixc)`` returns the (2, nh) detector
-    columns at column ``ixc``; ``band=(col0, two_bw)`` remaps detector
+    ``m`` is the :class:`_Mat` matrix view, ``img`` the
+    (rows, nh) image view. ``band=(col0, two_bw)`` remaps detector
     columns into a 2*bw band block starting at global column ``col0``
     (lines whose columns miss the band are zeroed).
     """
-    f_list, w_list = [], []
+    rows = img.shape[0]
+    slab_row = jax.lax.broadcasted_iota(jnp.int32, (SLAB, 1), 0)
+    line = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    f_vec = jnp.zeros((8, 1), jnp.float32)
+    w_vec = jnp.zeros((8, 1), jnp.float32)
     for jj in range(8):
-        j_g = j0 + jg * 8 + jj
-        f, w_eff, ixc, dx = _line_scalars(m, i_g, j_g, nw)
+        f, w_eff, ixc, dx = _line_scalars(m, i_g, j_base + jj, nw)
         if band is not None:
             col0, two_bw = band
             rel = ixc - col0
@@ -87,187 +155,214 @@ def _stage1_lines(m, img_cols, smem_ref, i_g, j0, jg, nw, band=None):
             w_eff = jnp.where((rel >= 0) & (rel <= two_bw - 2),
                               w_eff, 0.0)
             ixc = jnp.clip(rel, 0, two_bw - 2)
-        cols = img_cols(ixc)                      # (2, nh)
-        smem_ref[jj, :] = cols[0] * (1.0 - dx) + cols[1] * dx
-        f_list.append(f)
-        w_list.append(w_eff)
-    return (jnp.stack(f_list).reshape(8, 1),
-            jnp.stack(w_list).reshape(8, 1))
+        base = pl.multiple_of(jnp.minimum((ixc // 8) * 8, rows - SLAB), 8)
+        cols = img[pl.ds(base, SLAB), :]                  # (SLAB, nh)
+        off = ixc - base
+        wgt = jnp.where(slab_row == off, 1.0 - dx,
+                        jnp.where(slab_row == off + 1, dx, 0.0))
+        smem_ref[jj:jj + 1, :] = jnp.sum(cols * wgt, axis=0, keepdims=True)
+        f_vec = jnp.where(line == jj, f, f_vec)
+        w_vec = jnp.where(line == jj, w_eff, w_vec)
+    return f_vec, w_vec
 
 
-def _y_affine(m, i_g, j0, jg, f_vec):
+def _y_affine(m, i_g, j_base, f_vec):
     """The (8, 1) y-coefficients a, b with y(k) = a + b*k (O2 hoist)."""
     i_f = i_g.astype(jnp.float32)
-    j_base = (j0 + jg * 8).astype(jnp.float32)
-    j_off = jax.lax.broadcasted_iota(jnp.float32, (8, 1), 0)
-    j_vec = j_base + j_off                         # (8, 1)
+    j_vec = (j_base + jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+             ).astype(jnp.float32)
     a = (m[1, 0] * i_f + m[1, 1] * j_vec + m[1, 3]) * f_vec
     b = m[1, 2] * f_vec
     return a, b
 
 
-def _accumulate_projection(m, img_cols, out_ref, smem_ref, i0, j0,
-                           BI: int, GJ: int, nz: int, nw: int, nh: int,
-                           band=None):
+def _row_coords(y, nh: int):
+    y0 = jnp.floor(y)
+    iy = y0.astype(jnp.int32)
+    dy = y - y0
+    ok = (iy >= 0) & (iy <= nh - 2)
+    return jnp.clip(iy, 0, nh - 2), dy, ok
+
+
+def gather_interp(nh: int):
+    """Stage 2 of the sub-line kernel (Fig. 3b): linear interpolation in
+    the (8, nh_p) sub-line at (8, 128) row coordinates by lane gathers.
+
+    A v5e lane gather stays inside one (8, 128) vreg, so the sub-line is
+    read in 128-row chunks and each chunk's gather is kept where the
+    row falls in that chunk."""
+
+    def interp(sm_ref, y):
+        iyc, dy, ok = _row_coords(y, nh)
+        i1 = iyc + 1
+        s0 = s1 = None
+        for c in range(sm_ref.shape[1] // LANES):
+            src = sm_ref[:, c * LANES:(c + 1) * LANES]
+            g0 = jnp.take_along_axis(src, iyc % LANES, axis=1,
+                                     mode="promise_in_bounds")
+            g1 = jnp.take_along_axis(src, i1 % LANES, axis=1,
+                                     mode="promise_in_bounds")
+            if s0 is None:
+                s0, s1 = g0, g1
+            else:
+                s0 = jnp.where(iyc // LANES == c, g0, s0)
+                s1 = jnp.where(i1 // LANES == c, g1, s1)
+        return jnp.where(ok, s0 * (1.0 - dy) + s1 * dy, 0.0)
+
+    return interp
+
+
+def _accumulate_projection(m, img, out_ref, smem_ref, i0, j0, *, BI: int,
+                           GJ: int, nz: int, nw: int, nh: int, interp,
+                           kw: int = LANES, band=None):
     """Accumulate ONE projection into the (BI, BJ, nz) output block.
 
-    Shared between the per-projection grid kernel and the fused
-    multi-batch (``proj_loop``) kernel — and, via ``band``, by the
-    banded kernel family (see :func:`_stage1_lines` for the ``m`` /
-    ``img_cols`` / ``band`` calling convention).
+    Shared by the sub-line, one-hot and banded kernels (``interp`` is the
+    stage-2 interpolation, ``band`` the banded column remap, see
+    :func:`_stage1_lines`). ``kw`` is the k-chunk width.
     """
-    kh = nz // 2          # mirrored half
-    khp = nz - kh         # direct half (== kh, or kh+1 when nz odd)
-    for ii in range(BI):
+    khp = nz - nz // 2     # direct half (includes the odd-nz middle plane)
+    n_full, tail = divmod(nz, kw)
+
+    def group(t, carry):
+        ii = t // GJ
+        jlo = pl.multiple_of((t % GJ) * 8, 8)
         i_g = i0 + ii
-        for jg in range(GJ):
-            f_vec, w_vec = _stage1_lines(m, img_cols, smem_ref, i_g, j0,
-                                         jg, nw, band=band)
-            # --- stage 2: vectorized y interpolation (Fig. 3b) -------
-            a, b = _y_affine(m, i_g, j0, jg, f_vec)
-            k = jax.lax.broadcasted_iota(jnp.float32, (8, khp), 1)
-            y = a + b * k                                  # (8, khp)
-            sm = smem_ref[...]                             # (8, nh)
+        j_base = j0 + jlo
+        f_vec, w_vec = _stage1_lines(m, img, smem_ref, i_g, j_base, nw,
+                                     band=band)
+        a, b = _y_affine(m, i_g, j_base, f_vec)
+        a_m = (nh - 1.0) - a - b * (nz - 1.0)           # O3 mirror fold
 
-            def interp(yy):
-                y0 = jnp.floor(yy)
-                iy = y0.astype(jnp.int32)
-                dy = yy - y0
-                ok = (iy >= 0) & (iy <= nh - 2)
-                iyc = jnp.clip(iy, 0, nh - 2)
-                s0 = jnp.take_along_axis(sm, iyc, axis=1)
-                s1 = jnp.take_along_axis(sm, iyc + 1, axis=1)
-                v = s0 * (1.0 - dy) + s1 * dy
-                return jnp.where(ok, v, 0.0)
+        def chunk(c0, width):
+            k = (c0 + jax.lax.broadcasted_iota(jnp.int32, (8, kw), 1)
+                 ).astype(jnp.float32)
+            y = jnp.where(k < khp, a, a_m) + b * k
+            v = interp(smem_ref, y) * w_vec
+            if width < kw:
+                v = v[:, :width]
+            out_ref[ii, pl.ds(jlo, 8), pl.ds(c0, width)] += v
 
-            lo = interp(y) * w_vec                         # k in [0, khp)
-            y_m = (nh - 1.0) - y[:, :kh]                   # O3 mirror
-            hi = interp(y_m) * w_vec                       # k in [khp, nz)
-            jlo = jg * 8
-            out_ref[ii, jlo:jlo + 8, :khp] += lo
-            out_ref[ii, jlo:jlo + 8, khp:] += hi[:, ::-1]
+        if n_full:
+            def full(c, carry_):
+                chunk(pl.multiple_of(c * kw, kw), kw)
+                return carry_
+            jax.lax.fori_loop(0, n_full, full, 0)
+        if tail:
+            chunk(n_full * kw, tail)
+        return carry
 
-
-def _make_kernel(BI: int, BJ: int, nz: int, nw: int, nh: int):
-    GJ = BJ // 8  # groups of 8 lines (sublanes)
-
-    def kernel(mat_ref, img_ref, out_ref, smem_ref):
-        s = pl.program_id(2)
-        ti = pl.program_id(0)
-        tj = pl.program_id(1)
-
-        @pl.when(s == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        _accumulate_projection(
-            mat_ref, lambda ixc: img_ref[pl.ds(ixc, 2), :],
-            out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh)
-
-    return kernel
+    jax.lax.fori_loop(0, BI * GJ, group, 0)
 
 
-def _make_fused_kernel(BI: int, BJ: int, nz: int, nw: int, nh: int,
-                       nb: int):
-    """Fused multi-batch mode (``proj_loop``): the grid's projection
-    axis runs over nb-sized BATCHES and a ``fori_loop`` walks the batch
-    inside the kernel, so the (BI, BJ, nz) Z-slab accumulator is
-    read-modified-written once per nb projections instead of once per
-    projection — the paper's O1 loop order + O3 locality carried into
-    the kernel (1/nb output traffic, §3.1.3)."""
+def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
+                     nw: int, nh: int, interp, interpret: bool,
+                     kw: int = LANES, k_work: int = 0, band=None,
+                     bw: int = 0, acc=None):
+    """The one ``pallas_call`` behind all three kernels.
+
+    ``img_t`` is the padded (np, rows, nh_p) image stack — or, with
+    ``band`` (the flattened (np/nb * ni/BI * nj/BJ,) int32 band index
+    array, see ``backproject_banded.tile_bands``), the (np, n_bands,
+    2*bw, nh_p) band layout. ``nw``/``nh`` are the TRUE detector
+    extents. ``nb == 1`` is the per-projection grid. ``acc`` (an
+    (ni, nj, nz) volume, aliased to the output) is accumulated into
+    instead of starting from zero.
+    """
+    n_proj, nh_p = img_t.shape[0], img_t.shape[-1]
+    rows = img_t.shape[-2]
+    ni, nj, nz = vol_shape_xyz
+    BI, BJ = block
+    assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0, (ni, nj, block)
+    assert n_proj % nb == 0 and nb >= 1, (n_proj, nb)
+    assert rows % 8 == 0 and rows >= SLAB and nh_p % LANES == 0, \
+        img_t.shape
     GJ = BJ // 8
+    body = functools.partial(_accumulate_projection, BI=BI, GJ=GJ, nz=nz,
+                             nw=nw, nh=nh, interp=interp, kw=kw)
 
-    def kernel(mat_ref, img_ref, out_ref, smem_ref):
+    T_i, T_j = ni // BI, nj // BJ
+
+    def kernel(*refs):
+        refs = list(refs)
+        band_ref = refs.pop(0) if band is not None else None
+        mat_ref, img_ref = refs[:2]
+        acc_ref = refs[2] if acc is not None else None
+        out_ref, smem_ref = refs[-2:]
         ti = pl.program_id(0)
         tj = pl.program_id(1)
         sb = pl.program_id(2)
 
         @pl.when(sb == 0)
         def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
+            if acc_ref is None:
+                out_ref[...] = jnp.zeros_like(out_ref)
+            else:
+                out_ref[...] = acc_ref[...]
 
-        def body(b, carry):
-            _accumulate_projection(
-                mat_ref[b], lambda ixc: img_ref[b, pl.ds(ixc, 2), :],
-                out_ref, smem_ref, ti * BI, tj * BJ, BI, GJ, nz, nw, nh)
+        span = None if band is None else (
+            band_ref[(sb * T_i + ti) * T_j + tj] * bw, 2 * bw)
+
+        def one(b, carry):
+            body(_Mat(mat_ref, b), img_ref.at[b], out_ref, smem_ref,
+                 ti * BI, tj * BJ, band=span)
             return carry
 
-        jax.lax.fori_loop(0, nb, body, 0)
+        jax.lax.fori_loop(0, nb, one, 0)
 
-    return kernel
+    grid = (T_i, T_j, n_proj // nb)
+    out_spec = pl.BlockSpec((BI, BJ, nz), lambda ti, tj, s, *_: (ti, tj, 0))
+    mat_spec = pl.BlockSpec((nb, 3, 4), lambda ti, tj, s, *_: (s, 0, 0),
+                            memory_space=pltpu.SMEM)
+    if band is None:
+        img_spec = pl.BlockSpec((nb, rows, nh_p),
+                                lambda ti, tj, s: (s, 0, 0))
+    else:
+        img_spec = pl.BlockSpec(
+            (nb, None, rows, nh_p),
+            lambda ti, tj, s, band: (s, band[(s * T_i + ti) * T_j + tj],
+                                     0, 0))
+    in_specs = [mat_spec, img_spec]
+    args = [mat.astype(jnp.float32), img_t.astype(jnp.float32)]
+    if acc is not None:
+        in_specs.append(out_spec)
+        args.append(acc)
+    prefetch = [] if band is None else [band]
+    vmem = vmem_bytes(block, nz, rows, nh_p, nb, k_work)
+    if acc is not None:
+        vmem += 2 * BI * BJ * _pad_to(nz, LANES) * 4
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=grid,
+            in_specs=in_specs, out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((8, nh_p), jnp.float32)]),
+        input_output_aliases=({} if acc is None
+                              else {len(prefetch) + 2: 0}),
+        compiler_params=compiler_params(vmem),
+        interpret=interpret,
+    )(*prefetch, *args)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "interpret"),
+    static_argnames=("vol_shape_xyz", "block", "nb", "nw", "nh",
+                     "interpret"),
 )
 def backproject_subline_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
-                               vol_shape_xyz, *, block=(4, 8),
-                               interpret: bool = True) -> jnp.ndarray:
-    """Back-project transposed projections with the sub-line Pallas kernel.
+                               vol_shape_xyz, *, block=(8, 32), nb: int = 1,
+                               nw: int, nh: int,
+                               interpret: bool = False) -> jnp.ndarray:
+    """Back-project padded transposed projections with the sub-line kernel.
 
-    img_t (np, nw, nh) f32; mat (np, 3, 4) f32.
-    Returns vol_t (nx, ny, nz) f32. Requires ni % BI == nj % BJ == 0
-    (ops.py pads arbitrary i/j); any nz (odd handled by uneven halves).
+    img_t (np, rows, nh_p) f32 padded as :func:`padded_rows` /
+    :func:`padded_lanes` say; ``nw``/``nh`` the true detector extents;
+    mat (np, 3, 4) f32. ``nb > 1`` runs the fused in-kernel batch loop
+    (requires ``np % nb == 0``). Returns vol_t (ni, nj, nz) f32.
+    Requires ni % BI == nj % BJ == 0 (ops.py pads arbitrary i/j); any nz
+    (odd handled by uneven halves).
     """
-    n_proj, nw, nh = img_t.shape
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0, (ni, nj, block)
-
-    kernel = _make_kernel(BI, BJ, nz, nw, nh)
-    grid = (ni // BI, nj // BJ, n_proj)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 3, 4), lambda ti, tj, s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, nw, nh), lambda ti, tj, s: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz), lambda ti, tj, s: (ti, tj, 0)),
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-        interpret=interpret,
-    )(mat.astype(jnp.float32), img_t.astype(jnp.float32))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("vol_shape_xyz", "block", "nb", "interpret"),
-)
-def backproject_subline_fused(img_t: jnp.ndarray, mat: jnp.ndarray,
-                              vol_shape_xyz, *, block=(4, 8), nb: int = 8,
-                              interpret: bool = True) -> jnp.ndarray:
-    """Fused multi-batch (``proj_loop``) form of the sub-line kernel.
-
-    Identical math to :func:`backproject_subline_pallas`; the grid's
-    projection axis runs over ``n_proj // nb`` batches, each kernel call
-    receives an (nb, nw, nh) image block + (nb, 3, 4) matrix block and
-    loops the batch in-kernel. Requires ``n_proj % nb == 0`` (the
-    executor pads globally; ops.py falls back to the per-projection
-    grid otherwise).
-    """
-    n_proj, nw, nh = img_t.shape
-    ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
-    assert ni % BI == 0 and nj % BJ == 0 and BJ % 8 == 0, (ni, nj, block)
-    assert n_proj % nb == 0 and nb >= 1, (n_proj, nb)
-
-    kernel = _make_fused_kernel(BI, BJ, nz, nw, nh, nb)
-    grid = (ni // BI, nj // BJ, n_proj // nb)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nb, 3, 4), lambda ti, tj, s: (s, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((nb, nw, nh), lambda ti, tj, s: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((BI, BJ, nz), lambda ti, tj, s: (ti, tj, 0)),
-        out_shape=jax.ShapeDtypeStruct((ni, nj, nz), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((8, nh), jnp.float32)],
-        interpret=interpret,
-    )(mat.astype(jnp.float32), img_t.astype(jnp.float32))
+    return backproject_call(img_t, mat, tuple(vol_shape_xyz), block=block,
+                            nb=nb, nw=nw, nh=nh, interp=gather_interp(nh),
+                            interpret=interpret)

@@ -1,26 +1,25 @@
 """Jitted public wrappers for the Pallas back-projection kernels.
 
-Handles arbitrary problem shapes by padding the volume tile grid (voxel
+Handles arbitrary problem shapes by padding: the volume tile grid (voxel
 lines outside the true volume compute garbage that is sliced away; their
 projections may be off-detector, which the in-kernel masks already
-zero — padding only costs compute, never correctness).
+zero — padding only costs compute, never correctness) and the detector
+to the kernels' TPU alignment (zero columns/rows past the true extents,
+which the kernels' validity masks never admit).
 
-On real TPUs set interpret=False; the CPU CI in this repo always runs
-interpret=True (kernel body executed in Python by the Pallas interpreter).
+``interpret=None`` (the default) runs the Pallas interpreter exactly when
+the default backend is the CPU (``runtime.planner.resolve_interpret``);
+an explicit ``interpret=True`` on a TPU is an error.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax.numpy as jnp
 
 from .backproject_banded import backproject_banded as _backproject_banded
-from .backproject_onehot import (backproject_onehot_fused,
-                                 backproject_onehot_pallas)
-from .backproject_subline import (backproject_subline_fused,
-                                  backproject_subline_pallas,
-                                  fused_batch_ok)
+from .backproject_onehot import backproject_onehot_pallas
+from .backproject_subline import (_pad_to, backproject_subline_pallas,
+                                  fused_batch_ok, padded_lanes, padded_rows)
 
 # KernelSpec contract (core.variants.REGISTRY): the call-time options each
 # public wrapper consumes. The registry's Pallas KernelSpecs must declare
@@ -37,15 +36,16 @@ ACCEPTED_OPTIONS = {
 }
 
 
-def _pad_to(n: int, b: int) -> int:
-    return ((n + b - 1) // b) * b
+def default_block(ni: int, nj: int):
+    """(BI, BJ) voxel-line tile of one grid cell: up to 8 x 32 lines
+    (BJ a multiple of the 8 sublanes), shrunk for small volumes so they
+    are not padded far past their extent."""
+    return (max(1, min(8, ni)), min(32, _pad_to(nj, 8)))
 
 
-def _fused_ok(img_t, nb: int, proj_loop: bool) -> bool:
-    """Fused-mode eligibility (see kernels.backproject_subline
-    ``fused_batch_ok`` — the one definition, shared with the banded
-    wrapper's internal routing)."""
-    return fused_batch_ok(img_t.shape[0], nb, proj_loop)
+def _interpret(interpret):
+    from repro.runtime.planner import resolve_interpret
+    return resolve_interpret(interpret)
 
 
 def _run_padded(fn, img_t, mat, vol_shape_xyz, block, **kw):
@@ -57,7 +57,11 @@ def _run_padded(fn, img_t, mat, vol_shape_xyz, block, **kw):
     BI, BJ = block
     nip = _pad_to(ni, BI)
     njp = _pad_to(nj, BJ)
-    vol = fn(img_t, mat, (nip, njp, nz), block=block, **kw)
+    _, nw, nh = img_t.shape
+    rows, nh_p = padded_rows(nw), padded_lanes(nh)
+    if (rows, nh_p) != (nw, nh):
+        img_t = jnp.pad(img_t, ((0, 0), (0, rows - nw), (0, nh_p - nh)))
+    vol = fn(img_t, mat, (nip, njp, nz), block=block, nw=nw, nh=nh, **kw)
     if (nip, njp) != (ni, nj):
         vol = vol[:ni, :nj]
     return vol
@@ -65,8 +69,8 @@ def _run_padded(fn, img_t, mat, vol_shape_xyz, block, **kw):
 
 def backproject_subline(img_t: jnp.ndarray, mat: jnp.ndarray,
                         vol_shape_xyz, *, nb: int = 0,
-                        block=(4, 8), proj_loop: bool = False,
-                        interpret: bool = True) -> jnp.ndarray:
+                        block=None, proj_loop: bool = False,
+                        interpret=None) -> jnp.ndarray:
     """Paper Algorithm 1 as a Pallas kernel (symmetry_pf analogue).
 
     The output-stationary Pallas schedule holds the volume tile in VMEM
@@ -75,45 +79,45 @@ def backproject_subline(img_t: jnp.ndarray, mat: jnp.ndarray,
     nb-sized batches with an in-kernel ``fori_loop``, cutting the
     per-grid-step output read-modify-write by the batch factor (paper
     O5 inside the kernel); without it ``nb`` is accepted for registry-
-    signature uniformity but ignored. See DESIGN.md §2.
+    signature uniformity but ignored. ``block=None`` picks
+    :func:`default_block`. See DESIGN.md §2.
     """
-    if _fused_ok(img_t, nb, proj_loop):
-        return _run_padded(backproject_subline_fused, img_t, mat,
-                           tuple(vol_shape_xyz), block, nb=nb,
-                           interpret=interpret)
+    vol_shape_xyz = tuple(vol_shape_xyz)
+    block = tuple(block or default_block(*vol_shape_xyz[:2]))
+    nb_k = nb if fused_batch_ok(img_t.shape[0], nb, proj_loop) else 1
     return _run_padded(backproject_subline_pallas, img_t, mat,
-                       tuple(vol_shape_xyz), block, interpret=interpret)
+                       vol_shape_xyz, block, nb=nb_k,
+                       interpret=_interpret(interpret))
 
 
 def backproject_onehot(img_t: jnp.ndarray, mat: jnp.ndarray,
-                       vol_shape_xyz, *, nb: int = 0, block=(4, 8),
+                       vol_shape_xyz, *, nb: int = 0, block=None,
                        k_chunk: int = 128, proj_loop: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret=None) -> jnp.ndarray:
     """Beyond-paper MXU one-hot interpolation kernel (``proj_loop``:
     fused multi-batch mode, see :func:`backproject_subline`)."""
-    if _fused_ok(img_t, nb, proj_loop):
-        return _run_padded(backproject_onehot_fused, img_t, mat,
-                           tuple(vol_shape_xyz), block, k_chunk=k_chunk,
-                           nb=nb, interpret=interpret)
+    vol_shape_xyz = tuple(vol_shape_xyz)
+    block = tuple(block or default_block(*vol_shape_xyz[:2]))
+    nb_k = nb if fused_batch_ok(img_t.shape[0], nb, proj_loop) else 1
     return _run_padded(backproject_onehot_pallas, img_t, mat,
-                       tuple(vol_shape_xyz), block, k_chunk=k_chunk,
-                       interpret=interpret)
+                       vol_shape_xyz, block, k_chunk=k_chunk, nb=nb_k,
+                       interpret=_interpret(interpret))
 
 
 def backproject_banded(img_t: jnp.ndarray, mat: jnp.ndarray,
-                       vol_shape_xyz, *, nb: int = 0, block=(4, 8),
+                       vol_shape_xyz, *, nb: int = 0, block=None,
                        bw: int = 32, proj_loop: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret=None) -> jnp.ndarray:
     """Beyond-paper geometry-prefetched banded kernel (C3): streams only
     the ~2*bw detector columns each (tile, projection) pair touches.
     ``proj_loop`` shares one band per nb-projection batch (the kernel
     wrapper widens bw until the batch union fits)."""
     ni, nj, nz = vol_shape_xyz
-    BI, BJ = block
+    BI, BJ = block = tuple(block or default_block(ni, nj))
     nip, njp = _pad_to(ni, BI), _pad_to(nj, BJ)
     vol = _backproject_banded(img_t, mat, (nip, njp, nz), block=block,
                               bw=bw, nb=nb, proj_loop=proj_loop,
-                              interpret=interpret)
+                              interpret=_interpret(interpret))
     if (nip, njp) != (ni, nj):
         vol = vol[:ni, :nj]
     return vol

@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512" + (
-    " " + os.environ.get("REPRO_EXTRA_XLA_FLAGS", ""))
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first statements in this module — jax
-locks the device count at first initialization, and the production mesh
-needs 512 placeholder host devices.
+:func:`main` forces 512 placeholder host devices (``XLA_FLAGS``) before
+anything initializes a jax backend — jax locks the device count at first
+initialization, and the production mesh needs them. Importing this
+module changes no global state.
 
 Per cell this produces (and prints):
   * compiled.memory_analysis()  — proves the per-device footprint fits;
@@ -26,6 +23,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -255,8 +253,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             compiled = lowered.compile()
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: per-device
-                ca = ca[0] if ca else {}        # list of dicts
             hlo = compiled.as_text()
             hlo_text = hlo
             coll = collective_bytes(hlo)
@@ -356,7 +352,16 @@ LM_SHAPE_NAMES = ("train_4k", "prefill_32k", "decode_32k", "long_500k")
 CT_SHAPE_NAMES = ("P1", "P5", "P9", "P10")
 
 
+def force_host_devices(n: int = 512) -> None:
+    """Ask the CPU backend for ``n`` placeholder devices. Effective only
+    before the first jax backend initialization in this process."""
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={n} "
+        + os.environ.get("REPRO_EXTRA_XLA_FLAGS", ""))
+
+
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -11,24 +11,9 @@ import jax
 
 
 def make_mesh(shape, names):
-    """Version-portable ``jax.make_mesh``.
-
-    Newer jax wants explicit ``axis_types`` (we always mean Auto);
-    mid-0.4.x has ``jax.make_mesh`` without the kwarg; older 0.4.x has
-    neither and needs ``Mesh(create_device_mesh(...))`` directly.
-    """
-    if hasattr(jax, "make_mesh"):
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            try:
-                return jax.make_mesh(
-                    shape, names,
-                    axis_types=(axis_type.Auto,) * len(names))
-            except TypeError:
-                pass
-        return jax.make_mesh(shape, names)
-    from jax.experimental import mesh_utils
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), names)
+    """``jax.make_mesh`` with every axis explicitly Auto."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -209,6 +209,10 @@ class TunedConfig:
     # it the entry is invalidated and re-tuned (self-maintenance).
     # Pre-existing cache files lack the field -> 0.0 == always stale.
     tuned_at: float = 0.0
+    # candidates the search could not run, as (variant, error) pairs —
+    # e.g. a kernel Mosaic refuses to lower for this chip. Kept with the
+    # winner so a refusal is visible instead of silently dropped.
+    refused: Tuple[Tuple[str, str], ...] = ()
 
     @property
     def key(self) -> Tuple:
@@ -250,6 +254,8 @@ class TunedConfig:
         kw["proj_batch"] = None if pb is None else int(pb)
         # pre-batching cache entries lack the field: default to 1
         kw["max_batch"] = int(doc.get("max_batch", 1))
+        kw["refused"] = tuple((str(v), str(e))
+                              for v, e in doc.get("refused", []))
         return cls(**kw)
 
 
@@ -474,7 +480,7 @@ def _request_key(variant, base_plan, kernel_options: Dict) -> str:
     return key
 
 
-def _heuristic_config(geom, variant="auto", *, nb=8, interpret=True,
+def _heuristic_config(geom, variant="auto", *, nb=8, interpret=None,
                       tiling=None, memory_budget=None, proj_batch=None,
                       out=None, schedule=None, precision="f32",
                       solver="none", **kernel_options):
@@ -513,7 +519,7 @@ def resolve_config(geom, variant: str = "auto", *, cache=None,
 
 def resolve_plan(geom, *, variant="auto", tuning=None, tile_shape=None,
                  memory_budget=None, nb=8, proj_batch=None, out="host",
-                 interpret=True, schedule=None, request_batch=1,
+                 interpret=None, schedule=None, request_batch=1,
                  precision="f32", solver="none", **kernel_options):
     """Planner-level twin of :func:`resolve_config` (planner argument
     conventions; returns the plan only — the executor-level pipeline
@@ -767,7 +773,7 @@ def _pipeline_axis(cur: TunedConfig) -> List[TunedConfig]:
 
 def autotune(geom, variant: str = "auto", *, method: str = "fdk",
              nb: int = 8,
-             interpret: bool = True, tiling=None,
+             interpret: Optional[bool] = None, tiling=None,
              memory_budget: Optional[int] = None,
              proj_batch: Optional[int] = None, out: Optional[str] = None,
              schedule: Optional[str] = None, precision: str = "f32",
@@ -898,6 +904,7 @@ def autotune(geom, variant: str = "auto", *, method: str = "fdk",
 
     t_start = time.perf_counter()
     measured: Dict[Tuple, float] = {}
+    refused: List[Tuple[str, str]] = []
 
     def timed(cfg: TunedConfig) -> float:
         if cfg.key not in measured:
@@ -930,7 +937,8 @@ def autotune(geom, variant: str = "auto", *, method: str = "fdk",
             axes.append(_option_axis)
             axes.append(lambda c: _tile_axis(geom, c, memory_budget))
             axes.append(lambda c: _chunk_axis(geom, c, memory_budget))
-            axes.append(_precision_axis)
+            # no precision axis: a bf16 FDK volume is ~5e-4 off the f32
+            # one, outside the 1e-5 contract of the wide search
         axes.append(lambda c: _schedule_axis(c, memory_budget,
                                              pinned=schedule))
         axes.append(_pipeline_axis)
@@ -945,8 +953,12 @@ def autotune(geom, variant: str = "auto", *, method: str = "fdk",
                 break
             try:
                 t = timed(cand)
-            except Exception:
-                continue    # an unrunnable candidate never kills tuning
+            except Exception as e:
+                # an unrunnable candidate never kills tuning, but its
+                # refusal is recorded with the result
+                first = (str(e).splitlines() or [""])[0][:300]
+                refused.append((cand.variant, f"{type(e).__name__}: {first}"))
+                continue
             if t < best_t:
                 best, best_t = cand, t
 
@@ -957,7 +969,8 @@ def autotune(geom, variant: str = "auto", *, method: str = "fdk",
         pipeline_depth=best.pipeline_depth)
     winner = dataclasses.replace(
         best, wall_us=best_t * 1e6, baseline_us=baseline_t * 1e6,
-        source="measured", trials=len(measured), tuned_at=time.time())
+        source="measured", trials=len(measured), tuned_at=time.time(),
+        refused=tuple(refused))
     tcache.store(fp, rkey, winner)
     # tuner-outcome trajectory: one record per full search, keyed by
     # fingerprint, so the portability claim is a tracked number

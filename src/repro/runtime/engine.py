@@ -115,7 +115,7 @@ class TiledReconstructor:
                  tile_shape: Optional[Sequence[int]] = None,
                  memory_budget: Optional[int] = None,
                  nb: int = 8, proj_batch: Optional[int] = None,
-                 out: str = "host", interpret: bool = True,
+                 out: str = "host", interpret: Optional[bool] = None,
                  schedule: Optional[str] = None,
                  pipeline: str = "sync",
                  cache: Optional[ProgramCache] = None,

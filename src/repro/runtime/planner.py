@@ -431,6 +431,32 @@ class ReconPlan:
 
 
 # --------------------------------------------------------------------------
+# Platform-derived execution mode
+# --------------------------------------------------------------------------
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels run under the Pallas interpreter.
+
+    The ONE place this is decided: ``None`` (every default) means "on
+    the CPU backend only" — the interpreter is how CPU tests execute the
+    TPU kernels, and on a TPU the kernels are Mosaic-compiled. An
+    explicit ``interpret=True`` on an accelerator is an error rather than
+    a silent interpreter run that would hide the device.
+    """
+    import jax
+
+    backend = jax.default_backend()
+    if interpret is None:
+        return backend == "cpu"
+    if interpret and backend != "cpu":
+        raise ValueError(
+            f"interpret=True runs the Pallas interpreter, but the default "
+            f"backend is {backend!r}; leave interpret unset so the "
+            f"kernels compile for the device")
+    return bool(interpret)
+
+
+# --------------------------------------------------------------------------
 # Per-tile variant resolution (shared with the single-tile façade)
 # --------------------------------------------------------------------------
 
@@ -488,7 +514,7 @@ def _plan_reconstruction_impl(geom: CTGeometry,
                         nb: int = 8,
                         proj_batch: Optional[int] = None,
                         out: str = "host",
-                        interpret: bool = True,
+                        interpret: Optional[bool] = None,
                         schedule: Optional[str] = None,
                         request_batch: int = 1,
                         ingest: str = "offline",
@@ -509,7 +535,9 @@ def _plan_reconstruction_impl(geom: CTGeometry,
     proj_batch : projections streamed per kernel call, rounded UP to a
         multiple of ``nb``; ``None`` = all at once (a single chunk).
     out : "host" (numpy accumulator, device holds one tile) | "device".
-    interpret : forwarded to Pallas variants (CPU CI runs interpret=True).
+    interpret : Pallas interpreter or not; ``None`` derives it from the
+        platform (:func:`resolve_interpret` — True only on CPU, and an
+        explicit True on an accelerator is an error).
     schedule : "step" (device-resident scanned accumulators, one host
         crossing per step) | "chunk" (the PR-2 chunk-major loop;
         per-chunk host crossings, but also per-chunk — not whole-set —
@@ -581,6 +609,7 @@ def _plan_reconstruction_impl(geom: CTGeometry,
             request_batch=request_batch, precision=precision,
             solver=solver, **kernel_options)
     spec = get_spec(variant)
+    interpret = resolve_interpret(interpret)
     if precision not in ("f32", "bf16"):
         raise ValueError(
             f"precision must be 'f32' or 'bf16', got {precision!r}")

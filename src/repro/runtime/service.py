@@ -698,7 +698,7 @@ class ReconService:
             # keep the façade's heuristic default
             variant = "auto" if tuning is not None else "algorithm1_mp"
         kw = dict(
-            nb=opts.pop("nb", 8), interpret=opts.pop("interpret", True),
+            nb=opts.pop("nb", 8), interpret=opts.pop("interpret", None),
             tiling=opts.pop("tiling", None),
             memory_budget=opts.pop("memory_budget", None),
             proj_batch=opts.pop("proj_batch", None),

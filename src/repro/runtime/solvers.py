@@ -532,7 +532,7 @@ def solve(projections: jnp.ndarray, geom: CTGeometry,
           method: str = "sart", *, n_iters: int = 10, relax: float = 0.9,
           x0=None, tv_weight: float = 0.005, tv_inner: Optional[int] = None,
           oversample: float = 1.0, variant: str = "algorithm1_mp",
-          nb: int = 8, interpret: bool = True,
+          nb: int = 8, interpret: Optional[bool] = None,
           proj_batch: Optional[int] = None, schedule: Optional[str] = None,
           precision: str = "f32", cache: Optional[ProgramCache] = None,
           **kernel_options) -> Tuple[jnp.ndarray, SolveReport]:

@@ -209,6 +209,29 @@ def test_wide_search_parity_at_tolerance(setup, tmp_path):
     assert rel_rmse(tuned, ref) < 1e-5
 
 
+def test_refused_candidates_are_recorded(setup, tmp_path, monkeypatch):
+    """A candidate that cannot run (say a kernel the chip's compiler
+    refuses) does not end the search, and the winner names it with its
+    error, through the cache's JSON round trip."""
+    geom, projs = setup
+    measure = at._measure_config
+
+    def refuse(geom_, cfg, *a, **k):
+        if cfg.variant == "subline_batch_mp":
+            raise RuntimeError("Mosaic refused subline_batch_mp\nmore")
+        return measure(geom_, cfg, *a, **k)
+
+    monkeypatch.setattr(at, "_measure_config", refuse)
+    cache = TuningCache(str(tmp_path / "t.json"))
+    cfg = _tune(geom, projs, "auto", cache,
+                variants=("algorithm1_mp", "subline_batch_mp"))
+    assert cfg.variant == "algorithm1_mp"
+    assert ("subline_batch_mp",
+            "RuntimeError: Mosaic refused subline_batch_mp") in cfg.refused
+    (entry,) = cache.entries()[fingerprint_key()].values()
+    assert TunedConfig.from_json(entry).refused == cfg.refused
+
+
 def test_explicit_request_never_resolves_auto_winner(setup, tmp_path):
     """An auto-tuned winner may carry a different variant; a request
     that NAMES a variant must not resolve it (scoped request keys) —

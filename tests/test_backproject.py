@@ -4,6 +4,7 @@ baseline to the paper's own validation bar (RMSE < 1e-5 relative)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -28,6 +29,25 @@ def ref(small_geom, small_ct_data):
 @pytest.mark.parametrize("fn", [bp_transpose, bp_share, bp_symmetry,
                                 bp_subline])
 def test_ladder_matches_baseline(fn, small_geom, small_ct_data, ref):
+    img, mats = small_ct_data
+    img_t = transpose_projections(img)
+    out = fn(img_t, mats, small_geom.volume_shape_xyz)
+    assert rel_rmse(out, ref) < BAR
+
+
+@pytest.fixture
+def hat_interp(monkeypatch):
+    """Trace the TPU's sub-line interpolation (the hat sum) on the CPU."""
+    from repro.core import backproject as bp
+    monkeypatch.setattr(bp, "_interp_by_hat", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("fn", [bp_subline, bp_subline_symmetry_batch])
+def test_hat_interpolation_matches_baseline(fn, hat_interp, small_geom,
+                                            small_ct_data, ref):
     img, mats = small_ct_data
     img_t = transpose_projections(img)
     out = fn(img_t, mats, small_geom.volume_shape_xyz)

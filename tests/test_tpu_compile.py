@@ -1,0 +1,169 @@
+"""Compile the chip's programs for a described TPU v5e, without the chip.
+
+The TPU compiler is installed with jaxlib, and it compiles for a chip
+that is described rather than attached. These tests catch what the
+Pallas interpreter cannot: Mosaic lowering refusals (gathers, reversals,
+vector loads from SMEM, unaligned slices), scoped-VMEM and SMEM
+overflows, and step programs that do not fit the chip's HBM.
+
+Every kernel registered as ``backend="pallas"`` in ``core.variants`` is
+compiled, per projection and fused (``proj_loop``), at the widths of
+paper Table 3 P5 and P9, and the step-major scan programs and the FDK
+filter are compiled at the tiles and view chunks ``chip_smoke.py`` runs.
+
+The topology is described inside a module fixture (never at import
+time): only one process may load the TPU library, and the test workers
+must all collect the same tests.
+"""
+
+import importlib.util
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.core import backproject as bp
+from repro.core.variants import REGISTRY
+from repro.kernels.backproject_banded import BAND_TABLE_ENTRIES, _banded_call
+from repro.kernels.backproject_onehot import backproject_onehot_pallas
+from repro.kernels.backproject_subline import (backproject_subline_pallas,
+                                               padded_lanes, padded_rows)
+from repro.kernels.ops import default_block
+from repro.runtime.executor import ProgramCache
+
+GiB = 2 ** 30
+# v5e: 16 GiB of HBM, of which XLA may allocate 15.75 GiB.
+HBM_LIMIT = 15.75 * GiB
+# Paper Table 3: (label, detector, volume); 512 views each.
+WIDTHS = [("P5", 512, 512), ("P9", 1024, 1024)]
+N_PROJ = 512
+
+
+def _chip_smoke():
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # compiles for a described chip are written to the persistent cache
+    # but can never be read back without one: keep the cache off
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                     # noqa: BLE001
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # the default backend is still the CPU: trace the TPU's forms
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(bp, "_interp_by_hat", lambda: True)
+        jax.clear_caches()
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_program(variant, det, vol, nb, sharding):
+    """(jitted kernel, argument shapes) for one padded kernel call."""
+    block = default_block(vol, vol)
+    rows, nh_p = padded_rows(det), padded_lanes(det)
+    shape = (vol, vol, vol)
+    if variant == "banded_pl":
+        bw = 32
+        tiles = (vol // block[0]) * (vol // block[1])
+        n = nb * max(1, BAND_TABLE_ENTRIES // tiles)
+        fn = lambda i, m, b: _banded_call(             # noqa: E731
+            i, m, b, None, shape, block=block, bw=bw, nw=det, nh=det,
+            nb=nb, interpret=False)
+        args = (_spec(sharding, (n, -(-det // bw), 2 * bw, nh_p)),
+                _spec(sharding, (n, 3, 4)),
+                _spec(sharding, (n // nb * tiles,), jnp.int32))
+        return jax.jit(fn), args
+    kernel = {"subline_pl": backproject_subline_pallas,
+              "onehot_pl": backproject_onehot_pallas}[variant]
+    fn = lambda i, m: kernel(i, m, shape, block=block, nb=nb,  # noqa: E731
+                             nw=det, nh=det, interpret=False)
+    n = 16
+    return jax.jit(fn), (_spec(sharding, (n, rows, nh_p)),
+                         _spec(sharding, (n, 3, 4)))
+
+
+PALLAS = sorted(n for n, s in REGISTRY.items() if s.is_pallas)
+
+
+def test_every_pallas_kernel_is_covered():
+    assert PALLAS == ["banded_pl", "onehot_pl", "subline_pl"]
+
+
+@pytest.mark.parametrize("label,det,vol", WIDTHS, ids=[w[0] for w in WIDTHS])
+@pytest.mark.parametrize("nb", [1, 8], ids=["per_projection", "proj_loop"])
+@pytest.mark.parametrize("variant", PALLAS)
+def test_pallas_kernel_compiles_for_v5e(one_chip, variant, nb, label, det,
+                                        vol):
+    fn, args = _kernel_program(variant, det, vol, nb, one_chip)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fits(compiled):
+    ma = compiled.memory_analysis()
+    used = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+    return used < HBM_LIMIT, used / GiB
+
+
+@pytest.mark.parametrize("variant,problem", [
+    ("baseline", "P5"), ("algorithm1_mp", "P5"), ("subline_pl", "P5"),
+    ("subline_pl", "P9")])
+def test_chip_smoke_step_program_fits_v5e(one_chip, variant, problem):
+    smoke = _chip_smoke()
+    _, _, bi, bj = smoke.REF_BOXES[0]
+    tile, det, chunk = {
+        ("baseline", "P5"): ((bi, bj, 512), 512, N_PROJ),
+        ("algorithm1_mp", "P5"): (smoke.P5_TILE, 512, N_PROJ),
+        ("subline_pl", "P5"): ((512, 512, 512), 512, N_PROJ),
+        ("subline_pl", "P9"): (smoke.P9_TILE, 1024, smoke.P9_PROJ_BATCH),
+    }[(variant, problem)]
+    opts = (("proj_loop", True),) if REGISTRY[variant].is_pallas else ()
+    n_chunks = N_PROJ // chunk
+    prog = ProgramCache().scan_program(
+        variant, tile, 8, "float32", False, opts, n_chunks=n_chunks,
+        chunk_size=chunk)
+    ok, used = _fits(prog.lower(
+        _spec(one_chip, (n_chunks, chunk, det, det)),
+        _spec(one_chip, (n_chunks, chunk, 3, 4))).compile())
+    assert ok, (variant, problem, used)
+
+
+@pytest.mark.parametrize("problem", ["P5", "P9"])
+def test_chip_smoke_filter_fits_v5e(one_chip, problem):
+    """The FDK filter of the views the smoke filters at once: all of
+    them at P5, ``P9_PROJ_BATCH`` at P9 (all 512 ask for 16 GiB)."""
+    from repro.configs.ct_paper import get_problem
+    from repro.core.filtering import fdk_filter_chunk
+
+    smoke = _chip_smoke()
+    geom = get_problem(problem).geometry()
+    chunk = N_PROJ if problem == "P5" else smoke.P9_PROJ_BATCH
+    fn = jax.jit(lambda p: fdk_filter_chunk(p, geom, N_PROJ))
+    ok, used = _fits(fn.lower(
+        _spec(one_chip, (chunk, geom.nh, geom.nw))).compile())
+    assert ok, (problem, used)
