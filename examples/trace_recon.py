@@ -8,9 +8,10 @@ produces:
   1. ``recon_trace.json`` — Chrome trace-event JSON. Open it at
      https://ui.perfetto.dev: the service worker, flusher, and stream
      threads are separate lanes; every ``compile`` span is one
-     ProgramCache jit miss; every ``step.dispatch`` span carries the
-     planner's roofline model (bytes moved, FLOPs, arithmetic
-     intensity) as span args.
+     ProgramCache jit miss; every ``step.dispatch`` span names its
+     variant, call shape and view count; every host chunk's upload is
+     a ``transfer.h2d`` span inside its ``filter.chunk``. Under
+     ``jax.profiler.trace`` the same spans are profiler annotations.
   2. The request-ID -> batch-dispatch linkage: each ``submit()`` mints
      a trace ID (returned on the future), and the ``service.dispatch``
      span that executed a k-wide batch lists all k IDs in its args —

@@ -71,13 +71,13 @@ from repro.core.filtering import fdk_filter_chunk
 from repro.core.geometry import CTGeometry, projection_matrices
 from repro.core.tiling import (
     TileSpec, make_tiles, pad_projection_batch, plan_proj_chunks,
-    tile_working_set_bytes, translate_matrices,
+    translate_matrices,
 )
 from repro.core.variants import get_spec
 from repro.runtime import telemetry
 from repro.runtime.planner import (
     PlanStep, ReconPlan, StepMajorSchedule, build_step_major,
-    partition_steps, resolve_tile_variant, step_cost,
+    partition_steps, resolve_tile_variant,
 )
 from repro.runtime.straggler import FleetStragglerBoard
 
@@ -493,25 +493,17 @@ class _AsyncFlushQueue:
             raise self._error
 
 
-# 8 fused multiply-adds per voxel-view update — the same
-# "ct-backproject" cost model as launch/roofline.py (model_flops =
-# 8 * vol^3 * n_views), applied per tile step so trace annotations and
-# the capacity model tell one arithmetic-intensity story.
-_FLOPS_PER_UPDATE = 8.0
-
-
-def _step_roofline(plan: ReconPlan, step: PlanStep, n_views: int) -> dict:
-    """Span args for one step dispatch: modeled bytes moved (the
-    planner's tile working-set model, ``core.tiling.
-    tile_working_set_bytes``) and FLOPs (``_FLOPS_PER_UPDATE`` per
-    voxel-view update over :func:`~repro.runtime.planner.step_cost`
-    voxels), plus the resulting arithmetic intensity."""
-    ws = int(tile_working_set_bytes(step.call_shape, plan.det_shape_wh,
-                                    nb=plan.nb))
-    flops = _FLOPS_PER_UPDATE * step_cost(step) * int(n_views)
-    return {"bytes": ws, "flops": flops,
-            "ai_flop_per_byte": round(flops / max(ws, 1), 3),
-            "voxels": int(step_cost(step)), "n_views": int(n_views)}
+def _to_device(x, **args):
+    """``x`` on the default device: a host (numpy) array through an
+    explicit ``jax.device_put`` under a ``transfer.h2d`` span; a
+    ``jax.Array`` as it is. The span closes when ``device_put`` returns
+    and never waits for the copy, so a traced run queues the same device
+    work as an untraced one; the copy's end is in the profiler's own
+    transfer events."""
+    if isinstance(x, jax.Array):
+        return x
+    with telemetry.span("transfer.h2d", bytes=int(x.nbytes), **args):
+        return jax.device_put(x)
 
 
 def _pad_mats(mats: jnp.ndarray, n_pad: int) -> jnp.ndarray:
@@ -870,13 +862,12 @@ class PlanExecutor:
         return None
 
     def _step_span(self, step: PlanStep, n_views: int, **extra):
-        """Telemetry span for one step dispatch, roofline-annotated
-        (bytes / FLOPs / arithmetic intensity — the args are only
-        computed when tracing is live)."""
-        sp = telemetry.span("step.dispatch", xla=True)
+        """Telemetry span for one step dispatch (the args are only
+        built when tracing is live)."""
+        sp = telemetry.span("step.dispatch")
         if sp.live:
             sp.set(variant=step.variant, call_shape=list(step.call_shape),
-                   **_step_roofline(self.plan, step, n_views), **extra)
+                   n_views=int(n_views), **extra)
         return sp
 
     def _backproject_chunk(self, vol, img_c: jnp.ndarray,
@@ -1305,9 +1296,11 @@ class PlanExecutor:
 
     def _chunk_inputs(self, projections: jnp.ndarray, mat_p: jnp.ndarray,
                       s0: int, s1: int):
-        """Filter + transpose the raw rows of one padded chunk [s0, s1)."""
+        """Upload, filter + transpose the raw rows of one padded chunk
+        [s0, s1)."""
         plan = self.plan
-        raw = projections[s0:min(s1, plan.n_proj)]
+        raw = _to_device(projections[s0:min(s1, plan.n_proj)],
+                         chunk=s0 // plan.chunk_size)
         img_c = bp.transpose_projections(
             fdk_filter_chunk(raw, self.geom, plan.n_proj))
         pad = (s1 - s0) - img_c.shape[0]
@@ -1775,14 +1768,15 @@ class StreamingExecutor:
         """Filter + transpose one ready chunk — the same float-op path
         as the offline :meth:`PlanExecutor._chunk_inputs`."""
         s0, s1 = self._chunk_bounds[c]
-        img_c = bp.transpose_projections(
-            fdk_filter_chunk(jnp.asarray(buf), self.geom,
-                             self._plan.n_proj))
-        pad = (s1 - s0) - img_c.shape[0]
-        if pad > 0:   # tail chunk: zero images pair with repeated matrices
-            img_c = jnp.concatenate(
-                [img_c, jnp.zeros((pad,) + img_c.shape[1:], img_c.dtype)],
-                axis=0)
+        with telemetry.span("filter.chunk", chunk=c, n_views=int(s1 - s0)):
+            img_c = bp.transpose_projections(
+                fdk_filter_chunk(_to_device(buf, chunk=c), self.geom,
+                                 self._plan.n_proj))
+            pad = (s1 - s0) - img_c.shape[0]
+            if pad > 0:   # tail chunk: zero images pair with repeated mats
+                img_c = jnp.concatenate(
+                    [img_c, jnp.zeros((pad,) + img_c.shape[1:],
+                                      img_c.dtype)], axis=0)
         return img_c, self._mat_p[s0:s1]
 
     def filtered(self, c: int):

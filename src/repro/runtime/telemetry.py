@@ -1,6 +1,6 @@
 """Process-wide telemetry: spans, metrics, trace IDs, exporters.
 
-One observability layer for the whole runtime (ISSUE 10). Four pieces:
+One observability layer for the whole runtime. Four pieces:
 
 * **Spans** — nestable wall-clock intervals on the monotonic clock
   (``time.perf_counter``), recorded per OS thread so the runtime's
@@ -32,8 +32,11 @@ One observability layer for the whole runtime (ISSUE 10). Four pieces:
   (``$REPRO_TUNE_TRAJECTORY``) — the ROADMAP "portability claim is a
   tracked number" item.
 
-Spans optionally wrap ``jax.profiler.TraceAnnotation`` (set
-``REPRO_TRACE_XLA=1``) so repro spans line up with XLA profiles.
+Every live span is also a ``jax.profiler.TraceAnnotation``, so in a
+profiled run the spans sit on the device trace's own host clock beside
+the chip's operations. While tracing is on, a ``gc.callbacks`` hook
+records each cyclic garbage collection as a ``python.gc`` span
+(``generation``, ``collected``).
 
 This module imports nothing from ``repro`` — every runtime layer may
 import it without cycles.
@@ -42,6 +45,7 @@ import it without cycles.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import itertools
 import json
 import math
@@ -51,6 +55,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "enabled", "enable", "disable", "tracing", "span", "instant",
@@ -67,9 +73,11 @@ __all__ = [
 # Checked FIRST by span()/instant(); everything else is behind it. A
 # plain module global read is the cheapest gate Python offers, and the
 # disabled path allocates nothing (shared _NULL singleton).
-_enabled: bool = os.environ.get("REPRO_TRACE", "") not in ("", "0")
+_enabled: bool = False
 
-_lock = threading.Lock()
+# re-entrant: a garbage collection may start, and record its span, on a
+# thread that already holds the lock
+_lock = threading.RLock()
 _events: List[Dict[str, Any]] = []
 _dropped = 0
 _MAX_EVENTS = 1_000_000          # hard cap; beyond it events are counted, not kept
@@ -83,16 +91,24 @@ def enabled() -> bool:
     return _enabled
 
 
-def enable(clear_events: bool = False) -> None:
+def _set_enabled(on: bool) -> None:
+    """The one switch: the recording flag and the GC hook together."""
     global _enabled
+    _enabled = bool(on)
+    if _enabled and _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
+    elif not _enabled and _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+
+
+def enable(clear_events: bool = False) -> None:
     if clear_events:
         clear()
-    _enabled = True
+    _set_enabled(True)
 
 
 def disable() -> None:
-    global _enabled
-    _enabled = False
+    _set_enabled(False)
 
 
 @contextmanager
@@ -105,13 +121,12 @@ def tracing(path: Optional[str] = None, clear_events: bool = True):
     Restores the previous enabled state on exit (nesting-safe), then
     writes the Chrome trace to ``path`` when given.
     """
-    global _enabled
     prev = _enabled
     enable(clear_events=clear_events)
     try:
         yield
     finally:
-        _enabled = prev
+        _set_enabled(prev)
         if path is not None:
             dump_trace(path)
 
@@ -151,7 +166,7 @@ def open_span_count() -> int:
 
 class _NullSpan:
     """Shared do-nothing span — the disabled path. ``live`` lets call
-    sites skip computing expensive annotations (roofline args)."""
+    sites skip work that only the trace needs."""
 
     __slots__ = ()
     live = False
@@ -168,23 +183,6 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
-# jax.profiler.TraceAnnotation is resolved lazily so telemetry stays
-# importable (and free) when jax is absent or REPRO_TRACE_XLA is unset.
-_XLA_ANNOTATE = os.environ.get("REPRO_TRACE_XLA", "") not in ("", "0")
-_xla_annotation_cls: Any = None
-
-
-def _xla_annotation(name: str):
-    global _xla_annotation_cls
-    if _xla_annotation_cls is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _xla_annotation_cls = TraceAnnotation
-        except Exception:                       # pragma: no cover - no jax
-            _xla_annotation_cls = False
-    return _xla_annotation_cls(name) if _xla_annotation_cls else None
-
-
 class Span:
     """One live span. Use via ``with telemetry.span(...) as sp:``;
     ``sp.set(k=v)`` attaches args any time before exit."""
@@ -192,15 +190,14 @@ class Span:
     __slots__ = ("name", "cat", "args", "id", "parent", "_t0", "_ann")
     live = True
 
-    def __init__(self, name: str, cat: str, args: Dict[str, Any],
-                 ann=None):
+    def __init__(self, name: str, cat: str, args: Dict[str, Any]):
         self.name = name
         self.cat = cat
         self.args = args
         self.id = 0
         self.parent = None
         self._t0 = 0.0
-        self._ann = ann
+        self._ann = None
 
     def set(self, **args) -> "Span":
         self.args.update(args)
@@ -215,15 +212,14 @@ class Span:
         stack.append(self)
         with _lock:
             _open_spans.add(self.id)
-        if self._ann is not None:
-            self._ann.__enter__()
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
@@ -240,14 +236,12 @@ class Span:
         return False
 
 
-def span(name: str, cat: str = "recon", xla: bool = False, **args):
-    """A nestable span on the calling thread's lane; no-op when
-    tracing is disabled. ``xla=True`` additionally wraps the interval
-    in ``jax.profiler.TraceAnnotation`` when ``REPRO_TRACE_XLA=1``."""
+def span(name: str, cat: str = "recon", **args):
+    """A nestable span on the calling thread's lane, also a profiler
+    annotation; no-op when tracing is disabled."""
     if not _enabled:
         return _NULL
-    ann = _xla_annotation(name) if (xla and _XLA_ANNOTATE) else None
-    return Span(name, cat, args, ann)
+    return Span(name, cat, args)
 
 
 def instant(name: str, cat: str = "recon", **args) -> None:
@@ -257,6 +251,23 @@ def instant(name: str, cat: str = "recon", **args) -> None:
     _record({"ph": "i", "name": name, "cat": cat, "s": "t",
              "ts": time.perf_counter() * 1e6,
              "tid": threading.current_thread().name, "args": args})
+
+
+def _gc_hook(phase: str, info: Dict[str, Any]) -> None:
+    """``gc.callbacks`` entry while tracing is on: a ``python.gc`` span
+    from a collection's start to its stop, on the collecting thread."""
+    if phase == "start":
+        sp = span("python.gc", cat="gc", generation=info["generation"])
+        if sp.live:
+            _tls.gc_span = sp.__enter__()
+        return
+    sp = getattr(_tls, "gc_span", None)
+    if sp is not None:
+        _tls.gc_span = None
+        sp.set(collected=info["collected"]).__exit__(None, None, None)
+
+
+_set_enabled(os.environ.get("REPRO_TRACE", "") not in ("", "0"))
 
 
 # --------------------------------------------------------------------------

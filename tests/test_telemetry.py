@@ -5,15 +5,19 @@ on: the disabled path records NOTHING (shared no-op span singleton),
 span trees are well-formed (every span closed, parent ends after its
 children, parent/child share a thread lane) across the sync, async,
 fleet, and streaming execution paths, ``compile`` spans match
-ProgramCache miss counts EXACTLY, step spans carry the planner's
-roofline model (bytes/FLOPs/AI — the 8-flops-per-update model of
-benchmarks/bench_roofline.py), ``dump_trace`` emits valid Chrome
+ProgramCache miss counts EXACTLY, ``step.dispatch`` spans are recorded,
+every host chunk's upload is one ``transfer.h2d`` span inside its
+``filter.chunk``, spans are profiler annotations in a
+``jax.profiler`` trace, garbage collections are ``python.gc`` spans
+while tracing is on, ``dump_trace`` emits valid Chrome
 trace-event JSON with one lane per thread, request trace IDs link
 k-wide batched dispatches back to all k submitted futures,
 ``ServiceStats`` survives concurrent submit+snapshot hammering without
 torn reads, and the absorbed ``LatencyHistogram`` keeps its exact API.
 """
 
+import gc
+import glob
 import json
 import threading
 
@@ -23,7 +27,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.runtime import telemetry
+from repro.runtime import executor, telemetry
 from repro.runtime.executor import FleetConfig, PlanExecutor, ProgramCache
 from repro.runtime.planner import plan_reconstruction
 from repro.runtime.service import LatencyHistogram, ReconService
@@ -172,23 +176,138 @@ def test_compile_spans_match_cache_misses_exactly(small_geom,
     _check_span_tree()
 
 
-def test_step_spans_carry_roofline_annotations(small_geom, small_ct_data):
+def test_step_dispatch_spans_are_recorded(small_geom, small_ct_data):
     img, _ = small_ct_data
     plan = plan_reconstruction(small_geom, "algorithm1_mp", nb=4)
     ex = PlanExecutor(small_geom, plan, ProgramCache())
     with telemetry.tracing():
         ex.reconstruct(img)
     steps = [e for e in _x_events() if e["name"] == "step.dispatch"]
-    assert steps
+    assert len(steps) == len(plan.steps)
     for e in steps:
         a = e["args"]
-        assert a["bytes"] > 0 and a["flops"] > 0
-        # the paper's model: 8 flops per voxel update
-        # (benchmarks/bench_roofline.py), n_views updates per voxel
-        assert a["flops"] == pytest.approx(
-            8.0 * a["voxels"] * a["n_views"])
-        assert a["ai_flop_per_byte"] == pytest.approx(
-            a["flops"] / a["bytes"], rel=1e-2)
+        assert a["variant"] == plan.steps[0].variant
+        assert a["n_views"] > 0 and len(a["call_shape"]) == 3
+
+
+# ---------------------------------------------------------------------------
+# host-to-device transfers, profiler annotations, garbage collection
+
+
+def _chunked_plan(small_geom):
+    return plan_reconstruction(small_geom, "algorithm1_mp", nb=4,
+                               proj_batch=4)
+
+
+def test_transfer_h2d_once_per_host_chunk(small_geom, small_ct_data):
+    img, _ = small_ct_data
+    host = np.asarray(img)
+    plan = _chunked_plan(small_geom)
+    assert len(plan.chunks) == 2
+    ex = PlanExecutor(small_geom, plan, ProgramCache())
+    with telemetry.tracing():
+        ex.reconstruct(host)
+    spans = _check_span_tree()
+    ups = sorted((e for e in spans.values() if e["name"] == "transfer.h2d"),
+                 key=lambda e: e["args"]["chunk"])
+    assert [e["args"]["chunk"] for e in ups] == [0, 1]
+    for e, (s0, s1) in zip(ups, plan.chunks):
+        assert e["args"]["bytes"] == host[s0:s1].nbytes
+        parent = spans[e["args"]["parent_id"]]
+        assert parent["name"] == "filter.chunk"
+        assert parent["args"]["chunk"] == e["args"]["chunk"]
+
+
+def test_traced_upload_does_not_wait_for_the_copy(monkeypatch):
+    # a traced run must queue the same device work as an untraced one
+    def no_wait(*a, **k):
+        raise AssertionError("the upload span waited for the copy")
+
+    monkeypatch.setattr(jax, "block_until_ready", no_wait)
+    host = np.arange(12, dtype=np.float32).reshape(3, 4)
+    with telemetry.tracing():
+        got = executor._to_device(host, chunk=0)
+    assert isinstance(got, jax.Array)
+    np.testing.assert_array_equal(np.asarray(got), host)
+    up, = [e for e in _x_events() if e["name"] == "transfer.h2d"]
+    assert up["args"]["bytes"] == host.nbytes and up["args"]["chunk"] == 0
+
+
+def test_no_transfer_span_for_device_input(small_geom, small_ct_data):
+    img, _ = small_ct_data
+    assert isinstance(img, jax.Array)
+    ex = PlanExecutor(small_geom, _chunked_plan(small_geom), ProgramCache())
+    with telemetry.tracing():
+        ex.reconstruct(img)
+    names = [e["name"] for e in _x_events()]
+    assert names.count("filter.chunk") == 2
+    assert "transfer.h2d" not in names
+
+
+def test_stream_chunks_upload_inside_their_filter_span(small_geom,
+                                                       small_ct_data):
+    img, _ = small_ct_data
+    pa = np.asarray(img)
+    with telemetry.tracing():
+        with ReconService() as svc:
+            session = svc.open_stream(small_geom, nb=4, proj_batch=4,
+                                      out="host")
+            for v in range(small_geom.n_proj):
+                session.push(pa[v], start=v)
+            session.close()
+    spans = _check_span_tree()
+    ups = [e for e in spans.values() if e["name"] == "transfer.h2d"]
+    assert sorted(e["args"]["chunk"] for e in ups) == [0, 1]
+    for e in ups:
+        assert e["args"]["bytes"] == pa[:4].nbytes
+        assert spans[e["args"]["parent_id"]]["name"] == "filter.chunk"
+
+
+def test_spans_are_profiler_annotations(small_geom, small_ct_data,
+                                        tmp_path):
+    img, _ = small_ct_data
+    ex = PlanExecutor(small_geom, _chunked_plan(small_geom), ProgramCache())
+    ex.reconstruct(np.asarray(img))       # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.tracing():
+            with telemetry.span("probe.outer"):
+                ex.reconstruct(np.asarray(img))
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    profile = jax.profiler.ProfileData.from_file(path)
+    host = {ev.name for plane in profile.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"probe.outer", "filter.chunk", "transfer.h2d",
+            "step.dispatch"} <= host
+
+
+def test_gc_collections_are_spans_while_tracing():
+    telemetry.disable()
+    assert telemetry._gc_hook not in gc.callbacks
+    with telemetry.tracing():
+        assert telemetry._gc_hook in gc.callbacks
+        with telemetry.span("outer"):
+            gc.collect()
+    assert telemetry._gc_hook not in gc.callbacks
+    spans = _check_span_tree()
+    full = [e for e in spans.values() if e["name"] == "python.gc"
+            and e["args"]["generation"] == 2]
+    assert full
+    outer = next(e for e in spans.values() if e["name"] == "outer")
+    assert full[-1]["args"]["parent_id"] == outer["args"]["span_id"]
+    assert full[-1]["args"]["collected"] >= 0
+    telemetry.clear()
+    gc.collect()                          # tracing off: nothing recorded
+    assert telemetry.events() == []
+
+
+def test_enable_and_disable_install_and_remove_the_gc_hook():
+    telemetry.enable()
+    telemetry.enable()
+    assert gc.callbacks.count(telemetry._gc_hook) == 1
+    telemetry.disable()
+    assert telemetry._gc_hook not in gc.callbacks
 
 
 def test_span_tree_sync_and_async_paths(small_geom, small_ct_data):
@@ -234,7 +353,7 @@ def test_span_tree_and_lanes_async_fleet(small_geom, small_ct_data,
                    if e["name"] == "step.dispatch"
                    and e["args"].get("schedule") == "fleet"]
     assert len(fleet_steps) == ex_fleet.last_fleet_report.n_steps
-    assert all("flops" in e["args"] for e in fleet_steps)
+    assert all(e["args"]["variant"] for e in fleet_steps)
 
     # the exported trace is valid Chrome trace-event JSON with one
     # tid per thread and a thread_name metadata row per lane
