@@ -34,6 +34,15 @@ one select + FMA and nothing is reversed. Stage 2 interpolates inside the
 sub-line with single-vreg lane gathers (one per 128-row chunk of the
 sub-line, merged by select): the only gather Mosaic lowers on v5e.
 
+Stage 2's k chunks are unrolled, so the scheduler interleaves them. A
+lane gather is a round trip through the XLU (push the pattern, permute,
+pop, with the pop 60 to 100 bundles after the push on v5e), longer than
+the rest of a chunk's work; in a rolled loop each chunk waited on its
+own gathers, and most of stage 2's bundles were empty. The chunks are
+independent (each writes its own 128 lanes of the output and reads only
+the sub-line and the group's scalars), so one chunk's gathers overlap
+the next one's arithmetic.
+
 Alignment: the wrappers in ops.py pad nw to a multiple of 8 (at least 16)
 and nh to a multiple of 128 with zeros, and pass the TRUE nw/nh for the
 validity masks and the mirror. nz is never padded; a partial last k chunk
@@ -243,11 +252,10 @@ def _accumulate_projection(m, img, out_ref, smem_ref, i0, j0, *, BI: int,
                 v = v[:, :width]
             out_ref[ii, pl.ds(jlo, 8), pl.ds(c0, width)] += v
 
-        if n_full:
-            def full(c, carry_):
-                chunk(pl.multiple_of(c * kw, kw), kw)
-                return carry_
-            jax.lax.fori_loop(0, n_full, full, 0)
+        # Unrolled: the chunks are independent, so the scheduler can
+        # interleave their gather chains (see the module docstring).
+        for c in range(n_full):
+            chunk(c * kw, kw)
         if tail:
             chunk(n_full * kw, tail)
         return carry

@@ -60,6 +60,31 @@ def test_onehot_kernel_sweep(n, det, nproj):
     assert rel_rmse(out, ref) < BAR
 
 
+@pytest.mark.parametrize("kernel", ["subline", "onehot"])
+def test_kernel_full_k_chunks(kernel):
+    """Lines of 300 voxels: two whole 128-wide k chunks (the first in
+    the direct half, the second across the mirror) and a 44-voxel tail.
+    The (8, 8) lines are those around the axis of a 300^3 volume, moved
+    there by shifting the matrices' translation column."""
+    n, det, nproj = 300, 300, 3
+    geom = standard_geometry(n=n, n_det=det, n_proj=nproj)
+    rng = np.random.RandomState(3)
+    img_t = transpose_projections(jnp.asarray(
+        rng.rand(nproj, geom.nh, geom.nw).astype(np.float32)))
+    mats = np.array(projection_matrices(geom))
+    mats[:, :, 3] += (mats[:, :, 0] + mats[:, :, 1]) * (n // 2 - 4)
+    mats = jnp.asarray(mats)
+    shape = (8, 8, n)
+    ref = backproject_ref(img_t, mats, shape)
+    if kernel == "subline":
+        out = backproject_subline(img_t, mats, shape, block=(8, 8))
+    else:
+        out = backproject_onehot(img_t, mats, shape, block=(8, 8),
+                                 k_chunk=128)
+    assert float(np.abs(np.asarray(ref)).min()) > 0
+    assert rel_rmse(out, ref) < BAR
+
+
 @pytest.mark.parametrize("block", [
     (1, 8), (2, 8),
     pytest.param((4, 16), marks=pytest.mark.slow),   # ~9 s each in

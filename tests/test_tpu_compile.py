@@ -11,24 +11,32 @@ compiled, per projection and fused (``proj_loop``), at the widths of
 paper Table 3 P5 and P9, and the step-major scan programs and the FDK
 filter are compiled at the tiles and view chunks ``chip_smoke.py`` runs.
 
+The sub-line kernel's Mosaic module is also lowered for a TPU at the
+benchmark's widths and read op by op: its stage-2 ``k`` chunks must be
+unrolled, so that the chip's scheduler can interleave their gathers.
+
 The topology is described inside a module fixture (never at import
 time): only one process may load the TPU library, and the test workers
 must all collect the same tests.
 """
 
+import base64
 import importlib.util
+import json
 import os
 
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.extend.mlir import ir
 
 from repro.core import backproject as bp
 from repro.core.variants import REGISTRY
 from repro.kernels.backproject_banded import BAND_TABLE_ENTRIES, _banded_call
 from repro.kernels.backproject_onehot import backproject_onehot_pallas
-from repro.kernels.backproject_subline import (backproject_subline_pallas,
+from repro.kernels.backproject_subline import (LANES,
+                                               backproject_subline_pallas,
                                                padded_lanes, padded_rows)
 from repro.kernels.ops import default_block
 from repro.runtime.executor import ProgramCache
@@ -121,6 +129,73 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, variant, nb, label, det,
     fn, args = _kernel_program(variant, det, vol, nb, one_chip)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mosaic_tree(det, vol):
+    """The sub-line kernel's Mosaic module at ``block=(8, 32)``, ``nb=8``
+    (the benchmark's options), lowered for a TPU on this host, as a
+    ``(op name, [children])`` tree."""
+    rows, nh_p = padded_rows(det), padded_lanes(det)
+    fn = jax.jit(lambda i, m: backproject_subline_pallas(
+        i, m, (vol, vol, vol), block=(8, 32), nb=8, nw=det, nh=det,
+        interpret=False))
+    module = fn.trace(
+        jax.ShapeDtypeStruct((16, rows, nh_p), jnp.float32),
+        jax.ShapeDtypeStruct((16, 3, 4), jnp.float32),
+    ).lower(lowering_platforms=("tpu",)).compiler_ir("stablehlo")
+    # each Pallas kernel is a tpu_custom_call whose backend config (JSON)
+    # holds the serialized Mosaic module
+    bodies = []
+
+    def collect(op):
+        if (op.name == "stablehlo.custom_call"
+                and ir.StringAttr(op.attributes["call_target_name"]).value
+                == "tpu_custom_call"):
+            config = json.loads(
+                ir.StringAttr(op.attributes["backend_config"]).value)
+            bodies.append(config.get("custom_call_config", {}).get("body"))
+        return ir.WalkResult.ADVANCE
+
+    module.operation.walk(collect)
+    assert len(bodies) == 1 and bodies[0], (
+        "expected one tpu_custom_call whose backend config holds "
+        f"custom_call_config.body, found {len(bodies)}: the TPU lowering "
+        "of Pallas kernels has changed")
+    ctx = module.context
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        kernel = ir.Module.parse(base64.b64decode(bodies[0]))
+
+        def tree(op):
+            return (op.name, [tree(o) for r in op.regions
+                              for b in r.blocks for o in b.operations])
+
+        return tree(kernel.operation)
+
+
+def _find(node, suffix):
+    """Every node of the tree whose op name ends with ``suffix``."""
+    name, kids = node
+    found = [node] if name.endswith(suffix) else []
+    return found + [n for k in kids for n in _find(k, suffix)]
+
+
+@pytest.mark.parametrize("label,det,vol", [("P5", 512, 512),
+                                           ("P7", 1024, 256)],
+                         ids=["P5", "P7"])
+def test_subline_stage2_chunks_unrolled(label, det, vol):
+    tree = _mosaic_tree(det, vol)
+    # the line-group loop: the innermost loop around stage 1's blend
+    groups = [f for f in _find(tree, "scf.for")
+              if _find(f, "vector.multi_reduction")
+              and not any(_find(g, "vector.multi_reduction")
+                          for g in _find(f, "scf.for")[1:])]
+    assert len(groups) == 1, label
+    assert len(_find(groups[0], "scf.for")) == 1, \
+        f"{label}: a loop is left inside the line-group loop"
+    # one gather per 128 detector rows, for both taps, in every chunk
+    n_gathers = 2 * (vol // LANES) * (padded_lanes(det) // LANES)
+    assert len(_find(tree, "tpu.dynamic_gather")) == n_gathers, label
 
 
 def _fits(compiled):
