@@ -125,37 +125,36 @@ def make_fleet_bp(variant: str, call_shape: Tuple[int, int, int], *,
     origin-folded scan, so per-lane output is bit-identical to the
     rb=None program and one dispatch serves k requests' step.
 
-    This is :func:`make_distributed_bp`'s translated-matrix trick lifted
+    This is :func:`make_distributed_bp`'s traced-origin trick lifted
     from mesh slabs to the fleet's per-device step queues: the origin
-    folds into the matrices' constant column INSIDE the program
-    (:func:`~repro.core.tiling.translate_matrices` under the jit), so
-    ONE compiled program per (variant, call_shape, chunk grid) serves
-    EVERY same-shape step on ANY device — a stolen or failed-over step
-    is the same program called with a different origin on a different
-    device, never a recompile. The ``lax.scan`` carries the step's
-    accumulator across all projection chunks device-resident, exactly
-    like the single-device step-major megaprogram.
+    places the kernel's box INSIDE the program
+    (:func:`~repro.core.variants.at_origin` under the jit: whole-volume
+    indices for an ``index_origin`` kernel, else folded into the
+    matrices' constant column), so ONE compiled program per (variant,
+    call_shape, chunk grid) serves EVERY same-shape step on ANY device —
+    a stolen or failed-over step is the same program called with a
+    different origin on a different device, never a recompile. The
+    ``lax.scan`` carries the step's accumulator across all projection
+    chunks device-resident, exactly like the single-device step-major
+    megaprogram.
 
     Non-jittable kernels (``KernelSpec.jittable=False`` — banded_pl
     reads concrete matrix values at trace time) fall back to a python
     chunk loop over concrete arrays; the origin fold and the
     one-host-crossing contract are unchanged.
     """
-    from repro.core.variants import get_spec
+    from repro.core.variants import at_origin, get_spec
 
     spec = get_spec(variant)
     opts = spec.resolve_options(
         {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
     shape = tuple(call_shape)
-    fn = spec.fn
+    fn = at_origin(spec, spec.fn)
     if spec.jittable:
         def one(img_s, mat_s, origin):
-            mat_s = translate_matrices(mat_s, origin[0], origin[1],
-                                       origin[2])
-
             def body(acc, xs):
                 img_c, mat_c = xs
-                return acc + fn(img_c, mat_c, shape, **opts), None
+                return acc + fn(img_c, mat_c, shape, origin, **opts), None
 
             acc, _ = jax.lax.scan(
                 body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
@@ -165,12 +164,10 @@ def make_fleet_bp(variant: str, call_shape: Tuple[int, int, int], *,
         return jax.jit(jax.vmap(one, in_axes=(0, None, None)))
 
     def prog(img_s, mat_s, origin):
-        mat_t = translate_matrices(mat_s, origin[0], origin[1], origin[2])
-
         def lane(img_l):
             acc = None
             for c in range(int(n_chunks)):
-                part = fn(img_l[c], mat_t[c], shape, **opts)
+                part = fn(img_l[c], mat_s[c], shape, origin, **opts)
                 acc = part if acc is None else acc + part
             return acc
         if rb is None:

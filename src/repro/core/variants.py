@@ -84,20 +84,22 @@ def _subline_batch(img_t, mat, vol_shape_xyz, nb: int = 8, **_):
 
 def _subline_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
                     interpret=None, block=None,
-                    proj_loop: bool = False, **_):
+                    proj_loop: bool = False, origin=None, **_):
     from repro.kernels import ops
     return ops.backproject_subline(img_t, mat, vol_shape_xyz, nb=nb,
                                    block=block, interpret=interpret,
-                                   proj_loop=proj_loop)
+                                   proj_loop=proj_loop, origin=origin)
 
 
 def _onehot_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
                    interpret=None, block=None,
-                   k_chunk: int = 128, proj_loop: bool = False, **_):
+                   k_chunk: int = 128, proj_loop: bool = False,
+                   origin=None, **_):
     from repro.kernels import ops
     return ops.backproject_onehot(img_t, mat, vol_shape_xyz, nb=nb,
                                   block=block, k_chunk=k_chunk,
-                                  interpret=interpret, proj_loop=proj_loop)
+                                  interpret=interpret, proj_loop=proj_loop,
+                                  origin=origin)
 
 
 def _banded_pallas(img_t, mat, vol_shape_xyz, nb: int = 8,
@@ -152,6 +154,14 @@ class KernelSpec:
         knobs a kernel takes — the spec advertises them (every key must
         be in ``options``). Heuristic defaults stay with the planner;
         this only widens the MEASURED search.
+    index_origin : whether ``fn`` takes ``origin=`` (a (2,) int32 array,
+        the whole-volume voxel index (i, j) of the call box's first
+        line) and computes its detector coordinates from whole-volume
+        indices. For the other kernels :func:`at_origin` folds the box
+        origin into the matrices' constant column, whose float32
+        rounding moves every sample by up to an ulp, so a sample within
+        an ulp of the detector's last column can land on the other side
+        of it than in an untiled call.
     """
 
     name: str
@@ -163,6 +173,7 @@ class KernelSpec:
     jittable: bool = True
     proj_loop: bool = False
     tuning_space: Tuple[Tuple[str, Tuple], ...] = ()
+    index_origin: bool = False
 
     @property
     def uses_symmetry(self) -> bool:
@@ -207,13 +218,13 @@ REGISTRY: Dict[str, KernelSpec] = {s.name: s for s in (
                 "localmem", "prefetch"),
                options=_PL_OPTS,
                slab_safe_fallback="subline_batch_mp", backend="pallas",
-               proj_loop=True, tuning_space=_PL_TUNING),
+               proj_loop=True, tuning_space=_PL_TUNING, index_origin=True),
     KernelSpec("onehot_pl", _onehot_pallas,
                ("transpose", "share", "symmetry", "subline", "batch",
                 "localmem", "prefetch", "mxu-interp"),
                options=_PL_OPTS | {"k_chunk"},
                slab_safe_fallback="subline_batch_mp", backend="pallas",
-               proj_loop=True, tuning_space=_PL_TUNING),
+               proj_loop=True, tuning_space=_PL_TUNING, index_origin=True),
     # jittable=False: the band schedule is computed from concrete matrix
     # values at trace time (np.asarray(mat) in the kernel wrapper)
     KernelSpec("banded_pl", _banded_pallas,
@@ -278,6 +289,33 @@ def get_spec(name: str) -> KernelSpec:
         raise KeyError(f"unknown back-projection variant {name!r}; "
                        f"have {sorted(REGISTRY)}")
     return REGISTRY[name]
+
+
+def at_origin(spec: KernelSpec, fn: Callable) -> Callable:
+    """``fn`` (``spec``'s kernel, or a wrapper of it) placed in the whole
+    volume: ``g(img_t, mat, vol_shape_xyz, origin=None, **opts)``.
+
+    ``origin`` is the call box's voxel origin ``(i0, j0, k0)``, a (3,)
+    float32 array (traced or concrete); ``None`` calls ``fn`` as it is.
+    A kernel with ``index_origin`` gets ``(i0, j0)`` as voxel indices
+    and only ``k0`` folded into the matrices (their x and z rows do not
+    depend on k, so those rows stay bit for bit the untranslated ones);
+    the others get the whole origin folded in."""
+    import jax.numpy as jnp
+    from .tiling import translate_matrices
+
+    def g(img_t, mat, vol_shape_xyz, origin=None, **opts):
+        if origin is None:
+            return fn(img_t, mat, vol_shape_xyz, **opts)
+        if spec.index_origin:
+            return fn(img_t, translate_matrices(mat, 0.0, 0.0, origin[2]),
+                      vol_shape_xyz, origin=origin[:2].astype(jnp.int32),
+                      **opts)
+        return fn(img_t, translate_matrices(mat, origin[0], origin[1],
+                                            origin[2]),
+                  vol_shape_xyz, **opts)
+
+    return g
 
 
 def get_variant(name: str) -> Callable:

@@ -64,10 +64,12 @@ def backproject_onehot_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
                               vol_shape_xyz, *, block=(8, 32),
                               k_chunk: int = LANES, nb: int = 1,
                               nw: int, nh: int,
-                              interpret: bool = False) -> jnp.ndarray:
+                              interpret: bool = False,
+                              origin=None) -> jnp.ndarray:
     """One-hot kernel on padded projections (see
-    ``backproject_subline_pallas`` for the layout contract); ``k_chunk``
-    is the k-chunk width of one contraction (a multiple of 128 on TPU)."""
+    ``backproject_subline_pallas`` for the layout contract and
+    ``origin``); ``k_chunk`` is the k-chunk width of one contraction (a
+    multiple of 128 on TPU)."""
     if not interpret and k_chunk % LANES:
         raise ValueError(
             f"onehot_pl: k_chunk={k_chunk} must be a multiple of {LANES} "
@@ -76,4 +78,4 @@ def backproject_onehot_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
     return backproject_call(img_t, mat, tuple(vol_shape_xyz), block=block,
                             nb=nb, nw=nw, nh=nh, interp=onehot_interp(nh),
                             interpret=interpret, kw=k_chunk,
-                            k_work=4 * 4 * nh_p * k_chunk)
+                            k_work=4 * 4 * nh_p * k_chunk, origin=origin)

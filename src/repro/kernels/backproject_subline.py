@@ -266,7 +266,7 @@ def _accumulate_projection(m, img, out_ref, smem_ref, i0, j0, *, BI: int,
 def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
                      nw: int, nh: int, interp, interpret: bool,
                      kw: int = LANES, k_work: int = 0, band=None,
-                     bw: int = 0, acc=None):
+                     bw: int = 0, acc=None, origin=None):
     """The one ``pallas_call`` behind all three kernels.
 
     ``img_t`` is the padded (np, rows, nh_p) image stack — or, with
@@ -275,7 +275,12 @@ def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
     2*bw, nh_p) band layout. ``nw``/``nh`` are the TRUE detector
     extents. ``nb == 1`` is the per-projection grid. ``acc`` (an
     (ni, nj, nz) volume, aliased to the output) is accumulated into
-    instead of starting from zero.
+    instead of starting from zero. ``origin`` (a (2,) int32 array) is
+    the whole-volume voxel index (i, j) of the call's first line: the
+    detector coordinates are then computed from whole-volume indices and
+    the untranslated matrices, bit for bit as an untiled call computes
+    them, so a sub-box's samples at the detector's edge fall on the same
+    side as the whole volume's.
     """
     n_proj, nh_p = img_t.shape[0], img_t.shape[-1]
     rows = img_t.shape[-2]
@@ -294,6 +299,7 @@ def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
     def kernel(*refs):
         refs = list(refs)
         band_ref = refs.pop(0) if band is not None else None
+        org_ref = refs.pop(0) if origin is not None else None
         mat_ref, img_ref = refs[:2]
         acc_ref = refs[2] if acc is not None else None
         out_ref, smem_ref = refs[-2:]
@@ -312,8 +318,11 @@ def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
             band_ref[(sb * T_i + ti) * T_j + tj] * bw, 2 * bw)
 
         def one(b, carry):
+            i0, j0 = ti * BI, tj * BJ
+            if org_ref is not None:
+                i0, j0 = i0 + org_ref[0], j0 + org_ref[1]
             body(_Mat(mat_ref, b), img_ref.at[b], out_ref, smem_ref,
-                 ti * BI, tj * BJ, band=span)
+                 i0, j0, band=span)
             return carry
 
         jax.lax.fori_loop(0, nb, one, 0)
@@ -324,18 +333,19 @@ def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
                             memory_space=pltpu.SMEM)
     if band is None:
         img_spec = pl.BlockSpec((nb, rows, nh_p),
-                                lambda ti, tj, s: (s, 0, 0))
+                                lambda ti, tj, s, *_: (s, 0, 0))
     else:
         img_spec = pl.BlockSpec(
             (nb, None, rows, nh_p),
-            lambda ti, tj, s, band: (s, band[(s * T_i + ti) * T_j + tj],
-                                     0, 0))
+            lambda ti, tj, s, band, *_: (
+                s, band[(s * T_i + ti) * T_j + tj], 0, 0))
     in_specs = [mat_spec, img_spec]
     args = [mat.astype(jnp.float32), img_t.astype(jnp.float32)]
     if acc is not None:
         in_specs.append(out_spec)
         args.append(acc)
-    prefetch = [] if band is None else [band]
+    prefetch = ([] if band is None else [band]) + (
+        [] if origin is None else [jnp.asarray(origin, jnp.int32)])
     vmem = vmem_bytes(block, nz, rows, nh_p, nb, k_work)
     if acc is not None:
         vmem += 2 * BI * BJ * _pad_to(nz, LANES) * 4
@@ -361,7 +371,8 @@ def backproject_call(img_t, mat, vol_shape_xyz, *, block, nb: int,
 def backproject_subline_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
                                vol_shape_xyz, *, block=(8, 32), nb: int = 1,
                                nw: int, nh: int,
-                               interpret: bool = False) -> jnp.ndarray:
+                               interpret: bool = False,
+                               origin=None) -> jnp.ndarray:
     """Back-project padded transposed projections with the sub-line kernel.
 
     img_t (np, rows, nh_p) f32 padded as :func:`padded_rows` /
@@ -369,8 +380,9 @@ def backproject_subline_pallas(img_t: jnp.ndarray, mat: jnp.ndarray,
     mat (np, 3, 4) f32. ``nb > 1`` runs the fused in-kernel batch loop
     (requires ``np % nb == 0``). Returns vol_t (ni, nj, nz) f32.
     Requires ni % BI == nj % BJ == 0 (ops.py pads arbitrary i/j); any nz
-    (odd handled by uneven halves).
+    (odd handled by uneven halves). ``origin``: see
+    :func:`backproject_call`.
     """
     return backproject_call(img_t, mat, tuple(vol_shape_xyz), block=block,
                             nb=nb, nw=nw, nh=nh, interp=gather_interp(nh),
-                            interpret=interpret)
+                            interpret=interpret, origin=origin)

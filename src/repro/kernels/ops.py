@@ -70,7 +70,7 @@ def _run_padded(fn, img_t, mat, vol_shape_xyz, block, **kw):
 def backproject_subline(img_t: jnp.ndarray, mat: jnp.ndarray,
                         vol_shape_xyz, *, nb: int = 0,
                         block=None, proj_loop: bool = False,
-                        interpret=None) -> jnp.ndarray:
+                        interpret=None, origin=None) -> jnp.ndarray:
     """Paper Algorithm 1 as a Pallas kernel (symmetry_pf analogue).
 
     The output-stationary Pallas schedule holds the volume tile in VMEM
@@ -80,28 +80,31 @@ def backproject_subline(img_t: jnp.ndarray, mat: jnp.ndarray,
     per-grid-step output read-modify-write by the batch factor (paper
     O5 inside the kernel); without it ``nb`` is accepted for registry-
     signature uniformity but ignored. ``block=None`` picks
-    :func:`default_block`. See DESIGN.md §2.
+    :func:`default_block`. ``origin`` (i, j) places the call's box in
+    the whole volume (``backproject_subline.backproject_call``). See
+    DESIGN.md §2.
     """
     vol_shape_xyz = tuple(vol_shape_xyz)
     block = tuple(block or default_block(*vol_shape_xyz[:2]))
     nb_k = nb if fused_batch_ok(img_t.shape[0], nb, proj_loop) else 1
     return _run_padded(backproject_subline_pallas, img_t, mat,
                        vol_shape_xyz, block, nb=nb_k,
-                       interpret=_interpret(interpret))
+                       interpret=_interpret(interpret), origin=origin)
 
 
 def backproject_onehot(img_t: jnp.ndarray, mat: jnp.ndarray,
                        vol_shape_xyz, *, nb: int = 0, block=None,
                        k_chunk: int = 128, proj_loop: bool = False,
-                       interpret=None) -> jnp.ndarray:
+                       interpret=None, origin=None) -> jnp.ndarray:
     """Beyond-paper MXU one-hot interpolation kernel (``proj_loop``:
-    fused multi-batch mode, see :func:`backproject_subline`)."""
+    fused multi-batch mode, and ``origin``: see
+    :func:`backproject_subline`)."""
     vol_shape_xyz = tuple(vol_shape_xyz)
     block = tuple(block or default_block(*vol_shape_xyz[:2]))
     nb_k = nb if fused_batch_ok(img_t.shape[0], nb, proj_loop) else 1
     return _run_padded(backproject_onehot_pallas, img_t, mat,
                        vol_shape_xyz, block, k_chunk=k_chunk, nb=nb_k,
-                       interpret=_interpret(interpret))
+                       interpret=_interpret(interpret), origin=origin)
 
 
 def backproject_banded(img_t: jnp.ndarray, mat: jnp.ndarray,

@@ -71,9 +71,8 @@ from repro.core.filtering import fdk_filter_chunk
 from repro.core.geometry import CTGeometry, projection_matrices
 from repro.core.tiling import (
     TileSpec, make_tiles, pad_projection_batch, plan_proj_chunks,
-    translate_matrices,
 )
-from repro.core.variants import get_spec
+from repro.core.variants import at_origin, get_spec
 from repro.runtime import telemetry
 from repro.runtime.planner import (
     PlanStep, ReconPlan, StepMajorSchedule, build_step_major,
@@ -166,7 +165,9 @@ class ProgramCache:
     def program(self, variant: str, call_shape: Tuple[int, int, int],
                 nb: int, dtype: str, interpret: bool,
                 options: Tuple = ()) -> Callable:
-        """Jitted ``prog(img_t_chunk, mats_chunk) -> vol_t(call_shape)``."""
+        """Jitted ``prog(img_t_chunk, mats_chunk, origin=None) ->
+        vol_t(call_shape)``; ``origin`` places the call's box in the
+        volume (:func:`~repro.core.variants.at_origin`)."""
         key = ("kernel", variant, tuple(call_shape), int(nb), str(dtype),
                bool(interpret), tuple(options))
 
@@ -175,8 +176,9 @@ class ProgramCache:
             opts = spec.resolve_options(
                 {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
             shape = tuple(call_shape)
-            fn = _with_precision(spec.fn, variant, dtype)
-            prog = lambda img, mat: fn(img, mat, shape, **opts)  # noqa: E731
+            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
+            prog = lambda img, mat, origin=None: fn(  # noqa: E731
+                img, mat, shape, origin, **opts)
             # non-jittable kernels (KernelSpec.jittable=False) inspect
             # concrete values at trace time; cache them un-wrapped
             return jax.jit(prog) if spec.jittable else prog
@@ -186,8 +188,8 @@ class ProgramCache:
     def batch_program(self, variant: str, call_shape: Tuple[int, int, int],
                       nb: int, dtype: str, interpret: bool,
                       options: Tuple = (), *, rb: int) -> Callable:
-        """rb-lane chunk-kernel program: ``prog(img_b, mats) ->
-        vol_b((rb,) + call_shape)`` where ``img_b`` stacks rb filtered
+        """rb-lane chunk-kernel program: ``prog(img_b, mats, origin=None)
+        -> vol_b((rb,) + call_shape)`` where ``img_b`` stacks rb filtered
         projection chunks ``(rb, chunk, nw, nh)`` over ONE shared
         matrix chunk.
 
@@ -207,12 +209,18 @@ class ProgramCache:
             opts = spec.resolve_options(
                 {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
             shape = tuple(call_shape)
-            fn = _with_precision(spec.fn, variant, dtype)
-            one = lambda img, mat: fn(img, mat, shape, **opts)  # noqa: E731
+            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
+
+            def lane(img, mat, origin):
+                return fn(img, mat, shape, origin, **opts)
             if spec.jittable:
-                return jax.jit(jax.vmap(one, in_axes=(0, None)))
-            return lambda img_b, mat: jnp.stack(
-                [one(img_b[r], mat) for r in range(int(rb))])
+                lanes = jax.vmap(lane, in_axes=(0, None, None))
+
+                def one(img_b, mat, origin=None):
+                    return lanes(img_b, mat, origin)
+                return jax.jit(one)
+            return lambda img_b, mat, origin=None: jnp.stack(
+                [lane(img_b[r], mat, origin) for r in range(int(rb))])
 
         return self.get_or_build(key, build)
 
@@ -220,9 +228,10 @@ class ProgramCache:
                      nb: int, dtype: str, interpret: bool,
                      options: Tuple = (), *, n_chunks: int,
                      chunk_size: int) -> Callable:
-        """Step-major megaprogram: ``prog(img_chunks, mat_chunks) ->
-        vol_t(call_shape)`` where the inputs are the STACKED chunk axes
-        ``(n_chunks, chunk_size, ...)``.
+        """Step-major megaprogram: ``prog(img_chunks, mat_chunks,
+        origin=None) -> vol_t(call_shape)`` where the inputs are the
+        STACKED chunk axes ``(n_chunks, chunk_size, ...)`` and
+        ``origin`` the step's box origin (see :meth:`program`).
 
         One ``lax.scan`` carries the call-shape accumulator across all
         projection chunks on device — the executor emits it to host once
@@ -239,12 +248,13 @@ class ProgramCache:
             opts = spec.resolve_options(
                 {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
             shape = tuple(call_shape)
-            fn = _with_precision(spec.fn, variant, dtype)
+            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
             if spec.jittable:
-                def prog(img_s, mat_s):
+                def prog(img_s, mat_s, origin=None):
                     def body(acc, xs):
                         img_c, mat_c = xs
-                        return acc + fn(img_c, mat_c, shape, **opts), None
+                        return acc + fn(img_c, mat_c, shape, origin,
+                                        **opts), None
                     acc, _ = jax.lax.scan(
                         body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
                     return acc
@@ -254,10 +264,10 @@ class ProgramCache:
             # values at trace time) cannot sit under lax.scan: fall back
             # to a python chunk loop with a DONATED device accumulator —
             # still device-resident, still one host crossing per step.
-            def prog(img_s, mat_s):
+            def prog(img_s, mat_s, origin=None):
                 acc = None
                 for c in range(int(n_chunks)):
-                    part = fn(img_s[c], mat_s[c], shape, **opts)
+                    part = fn(img_s[c], mat_s[c], shape, origin, **opts)
                     acc = part if acc is None else _acc_add(acc, part)
                 return acc
             return prog
@@ -269,9 +279,10 @@ class ProgramCache:
                            nb: int, dtype: str, interpret: bool,
                            options: Tuple = (), *, n_chunks: int,
                            chunk_size: int, rb: int) -> Callable:
-        """rb-batched step-major megaprogram: ``prog(img_b, mat_s) ->
-        vol_b((rb,) + call_shape)`` where ``img_b`` stacks ``rb``
-        requests' scan grids ``(rb, n_chunks, chunk_size, ...)`` and
+        """rb-batched step-major megaprogram: ``prog(img_b, mat_s,
+        origin=None) -> vol_b((rb,) + call_shape)`` where ``img_b``
+        stacks ``rb`` requests' scan grids ``(rb, n_chunks, chunk_size,
+        ...)`` and
         ``mat_s`` is the SHARED chunk-stacked matrix grid (same-bucket
         requests share the geometry, so one matrix stack serves all
         lanes).
@@ -294,23 +305,29 @@ class ProgramCache:
             opts = spec.resolve_options(
                 {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
             shape = tuple(call_shape)
-            fn = _with_precision(spec.fn, variant, dtype)
+            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
             if spec.jittable:
-                def one(img_s, mat_s):
+                def lane(img_s, mat_s, origin):
                     def body(acc, xs):
                         img_c, mat_c = xs
-                        return acc + fn(img_c, mat_c, shape, **opts), None
+                        return acc + fn(img_c, mat_c, shape, origin,
+                                        **opts), None
                     acc, _ = jax.lax.scan(
                         body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
                     return acc
-                return jax.jit(jax.vmap(one, in_axes=(0, None)))
+                lanes = jax.vmap(lane, in_axes=(0, None, None))
 
-            def prog(img_b, mat_s):
+                def one(img_b, mat_s, origin=None):
+                    return lanes(img_b, mat_s, origin)
+                return jax.jit(one)
+
+            def prog(img_b, mat_s, origin=None):
                 lanes = []
                 for r in range(int(rb)):
                     acc = None
                     for c in range(int(n_chunks)):
-                        part = fn(img_b[r, c], mat_s[c], shape, **opts)
+                        part = fn(img_b[r, c], mat_s[c], shape, origin,
+                                  **opts)
                         acc = part if acc is None else _acc_add(acc, part)
                     lanes.append(acc)
                 return jnp.stack(lanes)
@@ -666,6 +683,20 @@ def as_fleet_config(devices, *, max_retries_per_step: int = 2,
                        step_hook=step_hook)
 
 
+def _replicate(img_s: jnp.ndarray, mat_s: jnp.ndarray, dev, d: int):
+    """The fleet's chunk stack and matrices on device ``dev`` (worker
+    ``d``). A copy to a device that does not hold them yet is waited for
+    here, traced or not: the ``fleet.replicate`` span then lasts until
+    the copy has landed, and the worker's first step needs it anyway."""
+    if all(isinstance(a, jax.Array) and a.devices() == {dev}
+           for a in (img_s, mat_s)):
+        return img_s, mat_s
+    with telemetry.span("fleet.replicate", device=d,
+                        bytes=int(img_s.nbytes + mat_s.nbytes)):
+        return jax.block_until_ready((jax.device_put(img_s, dev),
+                                      jax.device_put(mat_s, dev)))
+
+
 class PlanExecutor:
     """Executes a :class:`ReconPlan` against projection data.
 
@@ -819,12 +850,13 @@ class PlanExecutor:
         return (np.zeros(shape, np.float32) if self.plan.out == "host"
                 else jnp.zeros(shape, jnp.float32))
 
-    @staticmethod
-    def _translated(mats: jnp.ndarray, step: PlanStep) -> jnp.ndarray:
-        if (step.i0, step.j0, step.k_off) == (0, 0, 0):
-            return mats
-        return translate_matrices(mats, float(step.i0), float(step.j0),
-                                  float(step.k_off))
+    def _origin(self, step: PlanStep) -> Optional[jnp.ndarray]:
+        """The step's box origin ``(i0, j0, k_off)`` for its program
+        (``None`` for the untiled plan's one call, whose programs then
+        trace as without an origin)."""
+        if self._single_full_call():
+            return None
+        return jnp.asarray([step.i0, step.j0, step.k_off], jnp.float32)
 
     def _chunks_for(self, n_padded: int):
         """Chunk schedule for the ACTUAL (padded) projection count.
@@ -887,7 +919,7 @@ class PlanExecutor:
         for step in plan.steps:
             prog = self._program(step.variant, step.call_shape)
             with self._step_span(step, n_views, schedule="chunk"):
-                out = prog(img_c, self._translated(mat_c, step))
+                out = prog(img_c, mat_c, self._origin(step))
             cur = self._step_writes(step, out)
             if not host:
                 for (i_s, j_s, k_s), piece in cur:
@@ -931,7 +963,7 @@ class PlanExecutor:
                                               sched)
                     with self._step_span(step, sched.n_chunks *
                                          sched.chunk_size, schedule="step"):
-                        out = prog(img_s, self._translated(mat_s, step))
+                        out = prog(img_s, mat_s, self._origin(step))
                     flush.put(self._step_writes(step, out))
             finally:
                 flush.close()
@@ -942,7 +974,7 @@ class PlanExecutor:
             prog = self._scan_program(step.variant, step.call_shape, sched)
             with self._step_span(step, sched.n_chunks * sched.chunk_size,
                                  schedule="step"):
-                out = prog(img_s, self._translated(mat_s, step))
+                out = prog(img_s, mat_s, self._origin(step))
             cur = self._step_writes(step, out)
             if host:
                 for sl, piece in pending:
@@ -992,7 +1024,7 @@ class PlanExecutor:
                     with self._step_span(step, sched.n_chunks *
                                          sched.chunk_size, schedule="step",
                                          rb=rb):
-                        out = prog(img_b, self._translated(mat_s, step))
+                        out = prog(img_b, mat_s, self._origin(step))
                     flush.put(fanout(step, out))
             finally:
                 flush.close()
@@ -1004,7 +1036,7 @@ class PlanExecutor:
                                             sched, rb)
             with self._step_span(step, sched.n_chunks * sched.chunk_size,
                                  schedule="step", rb=rb):
-                out = prog(img_b, self._translated(mat_s, step))
+                out = prog(img_b, mat_s, self._origin(step))
             if host:
                 for tgt, sl, piece in pending:
                     tgt[sl] += np.asarray(piece)
@@ -1055,6 +1087,12 @@ class PlanExecutor:
         stealing/failover machinery is untouched (a retried batched
         step re-runs all lanes; still idempotent, the writes were
         never flushed).
+
+        **Spans**, on each worker's lane: ``fleet.replicate`` (the copy
+        of the chunk stack onto a device that lacks it, until it has
+        landed), ``fleet.flush_wait`` (asking for the flush lock until
+        holding it) and ``fleet.flush`` (the step's downloads and host
+        adds under the lock).
         """
         cfg = fleet if fleet is not None else (self.fleet or FleetConfig())
         vols = list(vol) if isinstance(vol, (list, tuple)) else None
@@ -1126,8 +1164,7 @@ class PlanExecutor:
                         # replicate the chunk stack onto this device
                         # once, lazily: a spare that never takes work
                         # never pays the copy
-                        img_d = jax.device_put(img_s, dev)
-                        mat_d = jax.device_put(mat_s, dev)
+                        img_d, mat_d = _replicate(img_s, mat_s, dev, d)
                     prog = (self._fleet_program(step.variant,
                                                 step.call_shape, sched)
                             if rb is None else
@@ -1166,14 +1203,23 @@ class PlanExecutor:
                 dur = time.perf_counter() - t0
                 # flush the step's disjoint writes; order across steps
                 # is irrelevant (disjoint boxes into a zeroed volume)
-                with flush_lock:
-                    if rb is None:
-                        for sl, piece in self._step_writes(step, out):
-                            vol[sl] += np.asarray(piece)
-                    else:
-                        for r in range(rb):
-                            for sl, piece in self._step_writes(step, out[r]):
-                                vols[r][sl] += np.asarray(piece)
+                writes = ([(vol, sl, piece)
+                           for sl, piece in self._step_writes(step, out)]
+                          if rb is None else
+                          [(vols[r], sl, piece) for r in range(rb)
+                           for sl, piece in self._step_writes(step, out[r])])
+                with telemetry.span("fleet.flush_wait", device=d,
+                                    step_index=idx):
+                    flush_lock.acquire()
+                try:
+                    with telemetry.span("fleet.flush", device=d,
+                                        step_index=idx,
+                                        bytes=sum(p.nbytes
+                                                  for _, _, p in writes)):
+                        for tgt, sl, piece in writes:
+                            tgt[sl] += np.asarray(piece)
+                finally:
+                    flush_lock.release()
                 board.record(d, idx, dur)
                 with cond:
                     counts["outstanding"] -= 1
@@ -1278,17 +1324,17 @@ class PlanExecutor:
         plan = self.plan
         name = resolve_tile_variant(plan.variant, tile, plan.vol_shape_xyz[2])
         img_p, mat_p = pad_projection_batch(img_t, mats, plan.nb)
-        mat_p = translate_matrices(mat_p, float(tile.i0), float(tile.j0),
-                                   float(tile.k0))
+        origin = jnp.asarray([tile.i0, tile.j0, tile.k0], jnp.float32)
         chunks = self._chunks_for(img_p.shape[0])
         if plan.schedule == "step":
             sched = self._data_step_major(chunks)
             img_s, mat_s = _stack_chunks(img_p, mat_p, sched)
-            return self._scan_program(name, tile.shape, sched)(img_s, mat_s)
+            return self._scan_program(name, tile.shape, sched)(
+                img_s, mat_s, origin)
         prog = self._program(name, tile.shape)
         acc = None
         for s0, s1 in chunks:
-            part = prog(img_p[s0:s1], mat_p[s0:s1])
+            part = prog(img_p[s0:s1], mat_p[s0:s1], origin)
             acc = part if acc is None else acc + part
         return acc
 
@@ -1823,7 +1869,7 @@ class StreamingExecutor:
             ex = self._ex
             for i, step in enumerate(self._plan.steps):
                 prog = ex._program(step.variant, step.call_shape)
-                self.accept_part(i, prog(img_c, ex._translated(mat_c, step)))
+                self.accept_part(i, prog(img_c, mat_c, ex._origin(step)))
             self.chunk_done(c)
         self.add_busy(time.perf_counter() - t0)
 

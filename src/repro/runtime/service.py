@@ -1089,7 +1089,7 @@ class ReconService:
                     step.variant, step.call_shape, plan.nb,
                     ex._dtype, plan.interpret, plan.options,
                     rb=len(cores))
-                out_b = prog(img_b, ex._translated(mat_c, step))
+                out_b = prog(img_b, mat_c, ex._origin(step))
                 for r, core in enumerate(cores):
                     core.accept_part(i, out_b[r])
             wall = time.perf_counter() - t0

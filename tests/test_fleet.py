@@ -7,10 +7,10 @@ no-hardware CI lane (``XLA_FLAGS=--xla_force_host_platform_device_count
 initializes — the main test process keeps the default single device):
 
   * **parity** — the fleet reconstruction of a volume matches the
-    single-device step-major walk within tolerance (the origin folds
-    into the matrices INSIDE the fleet program, so float association
-    may differ from the host-side fold; disjoint boxes mean nothing
-    else can);
+    single-device step-major walk within tolerance (both place each
+    step's box from its origin inside the step program,
+    ``core.variants.at_origin``, but in different compiled programs;
+    disjoint boxes mean nothing else can differ);
   * **failover** — with one device's steps forcibly failed, the run
     completes BIT-IDENTICALLY via re-run on surviving devices, the
     struck device is retired, and its completion count is zero;
@@ -122,6 +122,59 @@ out["service_bucket_devices"] = stats.buckets[0].devices
 out["service_requests"] = stats.requests
 svc.close()
 
+# ---- the benchmark cell's path vs the plain reference ---------------------
+# subline_pl (interpret mode on the CPU), full-z (8, 8, nz) tiles, two
+# proj_batch chunks, host volume, the first 4 devices: the path of the
+# p9.fleet4 cell at the smoke size, checked as its runs are, at sampled
+# voxels against bench/reference.py on seeded smooth projections
+sys.path.insert(0, "bench")
+import reference, traffic
+import repro
+from repro.core.geometry import CTGeometry
+from repro.runtime import telemetry
+from repro.runtime.executor import PlanExecutor
+
+cell_geom = reference.geometry({"vol": 16, "det": 24, "n_proj": 16,
+                                "sad": 1000.0, "sdd": 1536.0,
+                                "extent": 256.0, "det_margin": 1.25})
+seed = 2 ** 31 + 15
+scan = np.asarray(traffic.make_projections(
+    traffic.seed_key(seed, 0), cell_geom["n_proj"], cell_geom["nh"],
+    cell_geom["nw"], 8))
+ijk = traffic.sample_voxels(cell_geom, 512, seed)
+cell_ref = reference.fdk_at(scan, cell_geom, ijk)
+cell_opts = repro.ReconOptions(variant="subline_pl", tiling=(8, 8, 16),
+                               proj_batch=8, out="host", schedule="step",
+                               devices=4)
+
+def cell_rmse():
+    vol = np.asarray(repro.reconstruct(scan, CTGeometry(**cell_geom),
+                                       method="fdk", options=cell_opts))
+    got = np.array(vol[ijk[:, 2], ijk[:, 1], ijk[:, 0]], np.float64)
+    return reference.rel_rmse(got, cell_ref)
+
+with telemetry.tracing():
+    out["cell_rel_rmse"] = cell_rmse()
+    evs = telemetry.events()
+out["cell_spans"] = {name: sorted(e["args"].get("device", -1) for e in evs
+                                  if e["name"] == name)
+                     for name in ("step.dispatch", "fleet.flush",
+                                  "fleet.flush_wait", "fleet.replicate")}
+out["cell_flush_bytes"] = sum(e["args"]["bytes"] for e in evs
+                              if e["name"] == "fleet.flush")
+out["cell_replicate_bytes"] = sorted(e["args"]["bytes"] for e in evs
+                                     if e["name"] == "fleet.replicate")
+
+# planted fault: the writes of the step at the volume's corner are dropped
+keep_writes = PlanExecutor._step_writes
+PlanExecutor._step_writes = staticmethod(
+    lambda step, o: () if (step.i0, step.j0) == (0, 0)
+    else keep_writes(step, o))
+try:
+    out["cell_dropped_step_rel_rmse"] = cell_rmse()
+finally:
+    PlanExecutor._step_writes = staticmethod(keep_writes)
+
 print("RESULT:" + json.dumps(out))
 """
 
@@ -188,3 +241,28 @@ def test_service_places_buckets_across_fleet(fleet_results):
     assert fleet_results["service_repeat_identical"]
     assert fleet_results["service_bucket_devices"] == 8
     assert fleet_results["service_requests"] == 2
+
+
+def test_fleet_cell_path_matches_the_plain_reference(fleet_results):
+    """The benchmark cell's path (sub-line kernel, full-z tiles, chunked
+    filter, host volume, 4 devices) agrees with ``bench/reference.py``
+    within the paper's 1e-5, and a step whose writes are dropped reads
+    far above it."""
+    assert fleet_results["cell_rel_rmse"] < 1e-5
+    assert fleet_results["cell_dropped_step_rel_rmse"] > 1e-3
+
+
+def test_fleet_spans_flush_and_replicate(fleet_results):
+    """One ``fleet.flush`` and one ``fleet.flush_wait`` per step, on the
+    step's device, and one ``fleet.replicate`` per device other than
+    device 0 (which already holds the views) that took work."""
+    spans = fleet_results["cell_spans"]
+    steps = spans["step.dispatch"]
+    assert len(steps) == 4
+    assert spans["fleet.flush"] == spans["fleet.flush_wait"] == steps
+    assert spans["fleet.replicate"] == sorted(set(steps) - {0})
+    # the 16^3 float32 volume is flushed once; each copy carries the
+    # 16 filtered views and their matrices
+    assert fleet_results["cell_flush_bytes"] == 16 ** 3 * 4
+    assert all(b > 16 * 24 * 24 * 4
+               for b in fleet_results["cell_replicate_bytes"])

@@ -6,7 +6,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import projection_matrices, standard_geometry, \
-    transpose_projections
+    translate_matrices, transpose_projections
 from repro.kernels import backproject_onehot, backproject_ref, \
     backproject_subline
 from repro.kernels.ref import subline_blend_ref
@@ -83,6 +83,34 @@ def test_kernel_full_k_chunks(kernel):
                                  k_chunk=128)
     assert float(np.abs(np.asarray(ref)).min()) > 0
     assert rel_rmse(out, ref) < BAR
+
+
+@pytest.mark.parametrize("kernel", ["subline", "onehot"])
+def test_kernel_origin_keeps_the_whole_volumes_edge_samples(kernel):
+    """A sub-box called with ``origin`` reads what the whole volume reads
+    there, down to a sample on the detector's last column; the same box
+    with its origin folded into the matrices does not. The matrix puts
+    line (13, 5) at column 23.0 of a 24-column detector in float32, just
+    past the last interpolable position (dropped), while the fold's
+    rounding of the constant column moves it to 22.999998 (kept)."""
+    f32 = np.float32
+    nw, nh, shape = 24, 16, (8, 8, 8)
+    mat = np.zeros((1, 3, 4), np.float32)
+    mat[0, 0] = [f32(0.9899673461914062), f32(0.027058102190494537), 0,
+                 f32(9.995133399963379)]
+    mat[0, 1, 3] = 5.5
+    mat[0, 2, 3] = 1.0
+    mat = jnp.asarray(mat)
+    img_t = jnp.ones((1, nw, nh), jnp.float32)
+    call = backproject_subline if kernel == "subline" else backproject_onehot
+    whole = np.asarray(call(img_t, mat, (16, 8, 8), block=(8, 8)))
+    box = np.asarray(call(img_t, mat, shape, block=(8, 8),
+                          origin=jnp.asarray([8, 0], jnp.int32)))
+    folded = np.asarray(call(img_t, translate_matrices(mat, 8.0, 0.0),
+                             shape, block=(8, 8)))
+    np.testing.assert_allclose(box, whole[8:], rtol=0, atol=1e-6)
+    assert (whole[13, 5] == 0).all()
+    assert (folded[5, 5] == 1).all()
 
 
 @pytest.mark.parametrize("block", [

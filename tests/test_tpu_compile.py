@@ -228,6 +228,25 @@ def test_chip_smoke_step_program_fits_v5e(one_chip, variant, problem):
     assert ok, (variant, problem, used)
 
 
+def test_fleet_step_program_fits_v5e(one_chip):
+    """The fleet's shared step program at the P9 tile, with the step
+    origin a traced argument that reaches the sub-line kernel as
+    scalar-prefetched voxel indices."""
+    smoke = _chip_smoke()
+    chunk = smoke.P9_PROJ_BATCH
+    n_chunks = N_PROJ // chunk
+    prog = ProgramCache().fleet_program(
+        "subline_pl", smoke.P9_TILE, 8, "float32", False,
+        (("proj_loop", True),), n_chunks=n_chunks, chunk_size=chunk)
+    compiled = prog.lower(
+        _spec(one_chip, (n_chunks, chunk, 1024, 1024)),
+        _spec(one_chip, (n_chunks, chunk, 3, 4)),
+        _spec(one_chip, (3,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    ok, used = _fits(compiled)
+    assert ok, used
+
+
 @pytest.mark.parametrize("problem", ["P5", "P9"])
 def test_chip_smoke_filter_fits_v5e(one_chip, problem):
     """The FDK filter of the views the smoke filters at once: all of
