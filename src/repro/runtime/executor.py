@@ -640,6 +640,12 @@ class FleetReport(telemetry.EmitMixin):
     how many steps migrated (``stolen``), how many re-ran after a
     failure (``retried``), which devices were retired (``dead_devices``)
     and which the straggler board flagged (``flagged_devices``).
+    ``late_copies`` counts the step inputs a worker had to copy off
+    another chip once the step programs were dispatching: a copy that
+    leaves a chip waits behind whatever that chip has queued. Origins
+    come from the host and replicas for every device with queued work
+    are issued before the first step, so only a device whose initial
+    queue was empty and that then steals adds one (its lazy replica).
     ``as_dict()``/``emit()`` follow the shared
     :class:`~repro.runtime.telemetry.EmitMixin` report contract."""
 
@@ -650,6 +656,7 @@ class FleetReport(telemetry.EmitMixin):
     retried: int
     dead_devices: Tuple[int, ...]
     flagged_devices: Tuple[int, ...]
+    late_copies: int = 0
 
 
 def as_fleet_config(devices, *, max_retries_per_step: int = 2,
@@ -683,18 +690,24 @@ def as_fleet_config(devices, *, max_retries_per_step: int = 2,
                        step_hook=step_hook)
 
 
-def _replicate(img_s: jnp.ndarray, mat_s: jnp.ndarray, dev, d: int):
-    """The fleet's chunk stack and matrices on device ``dev`` (worker
-    ``d``). A copy to a device that does not hold them yet is waited for
-    here, traced or not: the ``fleet.replicate`` span then lasts until
-    the copy has landed, and the worker's first step needs it anyway."""
+def _replicate(img_s: jnp.ndarray, mat_s: jnp.ndarray, dev):
+    """Start copying the fleet's chunk stack and matrices onto device
+    ``dev``; None where both are there already. The copies run
+    asynchronously: :func:`_landed` waits for them."""
     if all(isinstance(a, jax.Array) and a.devices() == {dev}
            for a in (img_s, mat_s)):
-        return img_s, mat_s
+        return None
+    return jax.device_put(img_s, dev), jax.device_put(mat_s, dev)
+
+
+def _landed(copy, d: int):
+    """Worker ``d``'s replica ``copy`` once it is on the device. The
+    wait is made traced or not: the ``fleet.replicate`` span then lasts
+    until the copy has landed, and the worker's first step needs it
+    anyway."""
     with telemetry.span("fleet.replicate", device=d,
-                        bytes=int(img_s.nbytes + mat_s.nbytes)):
-        return jax.block_until_ready((jax.device_put(img_s, dev),
-                                      jax.device_put(mat_s, dev)))
+                        bytes=int(sum(a.nbytes for a in copy))):
+        return jax.block_until_ready(copy)
 
 
 class PlanExecutor:
@@ -1060,12 +1073,18 @@ class PlanExecutor:
         The step list is partitioned into per-device work queues
         (``runtime.planner.partition_steps`` — LPT-balanced on modeled
         voxel work); the filtered chunk stack is replicated onto each
-        device that takes work (lazily — an idle spare pays nothing),
-        and one dispatcher thread per device drains its queue through
-        the shared origin-traced fleet program
-        (``ProgramCache.fleet_program``). Step outputs land in the host
-        volume's disjoint boxes, so completion order is irrelevant and
-        the result equals the single-device step-major walk.
+        device whose queue holds work before any step program is
+        dispatched (an idle spare pays nothing; one that steals copies
+        lazily), and one dispatcher thread per device drains its queue
+        through the shared origin-traced fleet program
+        (``ProgramCache.fleet_program``), each step's origin put onto
+        the worker's device from the host. A copy off another chip
+        would wait behind that chip's queued steps, so once steps
+        dispatch no worker reads an input that must leave another chip
+        (``FleetReport.late_copies`` counts the exceptions). Step
+        outputs land in the host volume's disjoint boxes, so completion
+        order is irrelevant and the result equals the single-device
+        step-major walk.
 
         **Work stealing**: an idle device first drains the fleet retry
         queue, then steals from the tail of another device's queue —
@@ -1106,13 +1125,18 @@ class PlanExecutor:
                                            0, 0, (), ()))
             return vol
         fs = partition_steps(steps, n_dev)
+        # before any step program: a copy off device 0 issued after its
+        # first step would wait out that whole step
+        copies = [_replicate(img_s, mat_s, dev) if q else None
+                  for dev, q in zip(devices, fs.queues)]
         board = FleetStragglerBoard(n_dev, window=cfg.straggler_window,
                                     ratio=cfg.straggler_ratio)
 
         cond = threading.Condition()
         deques = [collections.deque(q) for q in fs.queues]
         retry: collections.deque = collections.deque()
-        counts = {"outstanding": 0, "stolen": 0, "retried": 0, "done": 0}
+        counts = {"outstanding": 0, "stolen": 0, "retried": 0, "done": 0,
+                  "late_copies": 0}
         failures: collections.Counter = collections.Counter()  # per index
         strikes: collections.Counter = collections.Counter()   # per device
         dead: set = set()
@@ -1161,10 +1185,16 @@ class PlanExecutor:
                     if cfg.step_hook is not None:
                         cfg.step_hook(d, idx)
                     if img_d is None:
-                        # replicate the chunk stack onto this device
-                        # once, lazily: a spare that never takes work
-                        # never pays the copy
-                        img_d, mat_d = _replicate(img_s, mat_s, dev, d)
+                        copy = copies[d]
+                        if copy is None and not fs.queues[d]:
+                            # a spare that steals copies now, lazily:
+                            # one that never takes work never pays
+                            copy = _replicate(img_s, mat_s, dev)
+                            if copy is not None:
+                                with cond:
+                                    counts["late_copies"] += 1
+                        img_d, mat_d = ((img_s, mat_s) if copy is None
+                                        else _landed(copy, d))
                     prog = (self._fleet_program(step.variant,
                                                 step.call_shape, sched)
                             if rb is None else
@@ -1172,8 +1202,8 @@ class PlanExecutor:
                                                       step.call_shape,
                                                       sched, rb))
                     origin = jax.device_put(
-                        jnp.asarray([step.i0, step.j0, step.k_off],
-                                    jnp.float32), dev)
+                        np.asarray([step.i0, step.j0, step.k_off],
+                                   np.float32), dev)
                     with self._step_span(step, sched.n_chunks *
                                          sched.chunk_size, schedule="fleet",
                                          device=d, step_index=idx):
@@ -1251,7 +1281,8 @@ class PlanExecutor:
             steps_by_device=tuple(done_by_device),
             stolen=counts["stolen"], retried=counts["retried"],
             dead_devices=tuple(sorted(dead)),
-            flagged_devices=board.flagged))
+            flagged_devices=board.flagged,
+            late_copies=counts["late_copies"]))
         return vol
 
     def _record_fleet(self, report: FleetReport) -> None:

@@ -1,7 +1,7 @@
 """Reconstruction-fleet tests (subprocess: 8 forced host devices).
 
 The fleet shards the planner's step-major schedule across a device mesh
-(``PlanExecutor.execute_fleet``); these prove the four contracts on the
+(``PlanExecutor.execute_fleet``); these prove the fleet's contracts on the
 no-hardware CI lane (``XLA_FLAGS=--xla_force_host_platform_device_count
 =8``, in a subprocess because the device count must be fixed before jax
 initializes — the main test process keeps the default single device):
@@ -18,7 +18,11 @@ initializes — the main test process keeps the default single device):
     (stolen > 0) with output still bit-identical;
   * **poison step** — a step that fails everywhere exhausts its
     per-step retry budget and aborts the run (an incomplete volume must
-    never be returned).
+    never be returned);
+  * **no step input leaves a chip once steps dispatch** — origins are
+    put from the host and the view replicas are issued before the first
+    step program, so no chip waits behind another's queued step; only a
+    spare that steals copies late, which ``late_copies`` counts.
 
 The serving layer rides the same path: ``ReconService(devices="all")``
 buckets place every request across the fleet and surface steal/failover
@@ -79,6 +83,10 @@ out["fleet_steps_covered"] = int(sum(rep.steps_by_device))
 def fail_dev3(device, step):
     if device == 3:
         raise RuntimeError("injected device fault")
+    # the others stay busy meanwhile: a peer that ran dry could steal
+    # device 3's second step before it strikes out, and it would then
+    # never be retired
+    time.sleep(0.05)
 
 vol_fo, rep_fo = fleet_run(FleetConfig(step_hook=fail_dev3))
 out["failover_bit_identical"] = bool(np.array_equal(vol_fleet, vol_fo))
@@ -96,6 +104,23 @@ vol_st, rep_st = fleet_run(FleetConfig(step_hook=slow_dev0))
 out["steal_bit_identical"] = bool(np.array_equal(vol_fleet, vol_st))
 out["steal_stolen"] = rep_st.stolen
 out["steal_flagged"] = list(rep_st.flagged_devices)
+
+# ---- a spare steals: all 16 steps queued on device 0, none on device 1 --
+import repro.runtime.executor as exmod
+from repro.runtime.planner import FleetSchedule
+keep_partition = exmod.partition_steps
+exmod.partition_steps = lambda steps, n: FleetSchedule(
+    n_shards=n, queues=(tuple(range(len(steps))),) + ((),) * (n - 1),
+    loads=(len(steps),) + (0,) * (n - 1))
+try:
+    vol_sp, rep_sp = fleet_run(FleetConfig(
+        devices=tuple(jax.local_devices()[:2]), step_hook=slow_dev0))
+finally:
+    exmod.partition_steps = keep_partition
+out["spare_bit_identical"] = bool(np.array_equal(vol_fleet, vol_sp))
+out["spare_steps_by_device"] = list(rep_sp.steps_by_device)
+out["spare_late_copies"] = rep_sp.late_copies
+out["parity_late_copies"] = rep.late_copies
 
 # ---- poison step: fails on EVERY device -> abort, never a partial volume -
 def poison_step0(device, step):
@@ -153,9 +178,51 @@ def cell_rmse():
     got = np.array(vol[ijk[:, 2], ijk[:, 1], ijk[:, 0]], np.float64)
     return reference.rel_rmse(got, cell_ref)
 
-with telemetry.tracing():
-    out["cell_rel_rmse"] = cell_rmse()
-    evs = telemetry.events()
+# every jax.device_put made inside execute_fleet, in order with the step
+# program calls: what it was given (a host array, or the devices of a
+# jax.Array), its shape, its target, and whether a step program had been
+# called before it
+puts, calls = [], []
+keep_put, keep_fleet = jax.device_put, PlanExecutor.execute_fleet
+keep_prog = PlanExecutor._fleet_program
+in_fleet = threading.Event()
+
+def logged_put(x, device=None, *a, **k):
+    if in_fleet.is_set():
+        src = (sorted(d.id for d in x.devices())
+               if isinstance(x, jax.Array) else "host")
+        puts.append({"src": src, "dst": device.id, "shape": list(x.shape),
+                     "after_step": bool(calls)})
+    return keep_put(x, device, *a, **k)
+
+late = []
+
+def logged_fleet(self, *a, **k):
+    in_fleet.set()
+    try:
+        return keep_fleet(self, *a, **k)
+    finally:
+        in_fleet.clear()
+        late.append(self.last_fleet_report.late_copies)
+
+def logged_prog(self, *a, **k):
+    prog = keep_prog(self, *a, **k)
+    def call(*args):
+        calls.append(1)
+        return prog(*args)
+    return call
+
+jax.device_put, PlanExecutor.execute_fleet = logged_put, logged_fleet
+PlanExecutor._fleet_program = logged_prog
+try:
+    with telemetry.tracing():
+        out["cell_rel_rmse"] = cell_rmse()
+        evs = telemetry.events()
+finally:
+    jax.device_put, PlanExecutor.execute_fleet = keep_put, keep_fleet
+    PlanExecutor._fleet_program = keep_prog
+out["cell_puts"] = puts
+out["cell_late_copies"] = late
 out["cell_spans"] = {name: sorted(e["args"].get("device", -1) for e in evs
                                   if e["name"] == name)
                      for name in ("step.dispatch", "fleet.flush",
@@ -266,3 +333,32 @@ def test_fleet_spans_flush_and_replicate(fleet_results):
     assert fleet_results["cell_flush_bytes"] == 16 ** 3 * 4
     assert all(b > 16 * 24 * 24 * 4
                for b in fleet_results["cell_replicate_bytes"])
+
+
+def test_fleet_step_inputs_leave_no_chip_once_steps_dispatch(fleet_results):
+    """Inside ``execute_fleet`` on the cell's path (4 devices, one step
+    each), the only device-to-device copies are the view and matrix
+    replicas of devices 1 to 3, all issued before the first step program
+    is called, and every step origin is put from a host array: no step
+    waits for a copy that leaves a chip behind that chip's queued step.
+    ``late_copies`` reads 0 there and in the 8-device parity run."""
+    puts = fleet_results["cell_puts"]
+    cross = [p for p in puts if p["src"] != "host" and p["src"] != [p["dst"]]]
+    origins = [p for p in puts if p["shape"] == [3]]
+    assert sorted(p["dst"] for p in cross) == [1, 1, 2, 2, 3, 3]
+    assert all(len(p["shape"]) == 4 and not p["after_step"] for p in cross)
+    assert len(origins) == 4
+    assert all(p["src"] == "host" for p in origins)
+    assert fleet_results["cell_late_copies"] == [0]
+    assert fleet_results["parity_late_copies"] == 0
+
+
+def test_fleet_spare_that_steals_counts_one_late_copy(fleet_results):
+    """A device whose initial queue is empty replicates only once it
+    steals, and that lazy copy is the one ``late_copies`` counts; the
+    volume is bit-identical to the balanced run's."""
+    assert fleet_results["spare_steps_by_device"][1] >= 1
+    assert sum(fleet_results["spare_steps_by_device"]) == \
+        fleet_results["fleet_steps"]
+    assert fleet_results["spare_late_copies"] == 1
+    assert fleet_results["spare_bit_identical"]
