@@ -2,8 +2,7 @@
 
 Full P-sizes do not fit a 1-core CPU budget; each P is scaled by 1/8 per
 axis (shape RATIOS preserved: detector/volume/projection proportions are
-what drive the locality behaviour the paper studies). The full-size cells
-are exercised structurally by the dry-run (ct-backproject arch).
+what drive the locality behaviour the paper studies).
 """
 
 from __future__ import annotations

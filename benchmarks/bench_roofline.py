@@ -1,8 +1,8 @@
 """Paper Fig. 10 analogue: roofline placement of the top kernel.
 
 The paper uses Intel Advisor on dual Gold-6140; here the roofline terms
-come from the dry-run's compiled artifacts (launch/roofline.py, TPU v5e
-constants) plus an analytic arithmetic-intensity model of the kernels:
+come from an analytic arithmetic-intensity model of the kernels against
+TPU v5e constants:
 
     AI(subline)  ~ flops / bytes
       flops/update ~ 8   (two mixes + weight + accumulate)
@@ -14,9 +14,6 @@ bandwidth ceilings on CPUs.
 """
 
 from __future__ import annotations
-
-import glob
-import json
 
 from .common import emit
 
@@ -39,19 +36,6 @@ def run():
         emit(f"roofline/{name}", 0.0,
              f"AI={ai:.3f} bound={bound} "
              f"attainable_TFLOPs={attainable/1e12:.2f}")
-
-    # measured placement from dry-run artifacts
-    for fn in sorted(glob.glob("artifacts/dryrun/ct-backproject__*"
-                               "__pod16x16.json")):
-        with open(fn) as f:
-            rec = json.load(f)
-        if rec.get("status") != "ok":
-            continue
-        f_dev = rec["cost"]["flops_per_device"]
-        b_dev = rec["cost"]["bytes_per_device"]
-        ai = f_dev / max(b_dev, 1.0)
-        emit(f"roofline/dryrun_{rec['shape']}", 0.0,
-             f"AI={ai:.3f} flops_dev={f_dev:.2e} bytes_dev={b_dev:.2e}")
 
 
 def main():

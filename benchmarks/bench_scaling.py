@@ -4,15 +4,10 @@ The paper scales OpenMP threads 1..128 on multicore CPUs. This container
 has one core, so hardware thread scaling is not measurable; instead we
 measure the structural analogue the TPU mapping relies on — work-scaling
 across the voxel-line grid (j-block width), which is the unit the Pallas
-kernel parallelizes over — and report the dry-run-derived device-scaling
-(256 -> 512 chips) from the artifacts when present.
+kernel parallelizes over.
 """
 
 from __future__ import annotations
-
-import glob
-import json
-import os
 
 import numpy as np
 
@@ -43,18 +38,6 @@ def run():
             base = t
         emit(f"scaling/lines_1_over_{frac}", t * 1e6,
              f"work_frac={1/frac:.2f} time_frac={t/base:.2f}")
-
-    # device scaling from dry-run artifacts (single- vs multi-pod)
-    for fn in sorted(glob.glob("artifacts/dryrun/"
-                               "ct-backproject__P5__*.json")):
-        with open(fn) as f:
-            rec = json.load(f)
-        if rec.get("status") != "ok":
-            continue
-        emit(f"scaling/dryrun_{rec['mesh']}", 0.0,
-             f"chips={rec['chips']} "
-             f"flops_dev={rec['cost']['flops_per_device']:.2e} "
-             f"coll_MB={rec['collectives']['total_bytes']/1e6:.1f}")
 
 
 def main():

@@ -350,23 +350,3 @@ def bp_subline_symmetry_batch(img_t, mat, vol_shape_xyz, nb: int = 8):
         lambda im, mm: _bp_symmetry_single(im, mm, vol_shape_xyz,
                                            use_subline=True),
         img_t, mat, vol_shape_xyz, nb)
-
-
-@functools.partial(jax.jit, static_argnames=("vol_shape_xyz",))
-def bp_subline_symmetry_scan(img_t, mat, vol_shape_xyz):
-    """Algorithm 1 semantics with SEQUENTIAL per-projection accumulation.
-
-    Identical math to bp_subline_symmetry_batch but the in-batch vmap is
-    replaced by a scan: peak temporaries are one volume-sized working set
-    instead of nb of them (the vmap materializes nb copies of every
-    (ni,nj,nz) intermediate). Used by the distributed/multi-pod path
-    where per-device HBM bytes dominate (EXPERIMENTS.md §Perf, CT cell).
-    """
-    def body(vol, xs):
-        img_s, mat_s = xs
-        return vol + _bp_symmetry_single(img_s, mat_s, vol_shape_xyz,
-                                         use_subline=True), None
-
-    vol0 = jnp.zeros(vol_shape_xyz, jnp.float32)
-    vol, _ = jax.lax.scan(body, vol0, (img_t, mat))
-    return vol

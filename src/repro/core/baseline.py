@@ -19,7 +19,7 @@ contributes iff ``0 <= x <= nw-2+1`` is interpolable, i.e. ``floor(x)`` and
 ``floor(x)+1`` are both in-bounds (same for y), and ``z > 0``; otherwise the
 contribution is exactly zero. Gathers are index-clamped so out-of-range
 lanes read *some* valid element and are then masked — this keeps every
-variant (JAX, Pallas, distributed) bit-comparable.
+variant (JAX and Pallas) bit-comparable.
 """
 
 from __future__ import annotations

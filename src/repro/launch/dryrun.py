@@ -17,8 +17,6 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2.5-3b \
       --shape train_4k [--multi-pod] [--out artifacts/dryrun]
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
-  PYTHONPATH=src python -m repro.launch.dryrun --arch ct-backproject \
-      --shape P5 [--multi-pod]
 """
 
 import argparse
@@ -203,29 +201,6 @@ def _lower_lm_cell(arch: str, shape_name: str, mesh):
     return lowered, {"kind": "decode"}
 
 
-def _lower_ct_cell(problem_label: str, mesh):
-    """Distributed back-projection (iFDK-style, DESIGN.md §4)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.configs.ct_paper import get_problem
-    from repro.core.distributed import make_distributed_bp
-
-    prob = get_problem(problem_label)
-    geom = prob.geometry()
-    nb = 32
-    fn, specs = make_distributed_bp(geom, mesh, nb=nb)
-    img_spec, mat_spec, origin_spec, out_spec = specs
-    img_like = jax.ShapeDtypeStruct((nb, geom.nw, geom.nh), jnp.float32)
-    mat_like = jax.ShapeDtypeStruct((nb, 3, 4), jnp.float32)
-    origin_like = jax.ShapeDtypeStruct((2,), jnp.float32)
-    jf = jax.jit(fn, in_shardings=(NamedSharding(mesh, img_spec),
-                                   NamedSharding(mesh, mat_spec),
-                                   NamedSharding(mesh, origin_spec)),
-                 out_shardings=NamedSharding(mesh, out_spec))
-    lowered = jf.lower(img_like, mat_like, origin_like)
-    return lowered, {"kind": "ct-backproject", "nb": nb}
-
-
 # --------------------------------------------------------------------------
 # cell runner
 # --------------------------------------------------------------------------
@@ -242,10 +217,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
            "chips": n_chips}
     hlo_text = None
     try:
-        if arch == "ct-backproject":
-            lowered, info = _lower_ct_cell(shape_name, mesh)
-        else:
-            lowered, info = _lower_lm_cell(arch, shape_name, mesh)
+        lowered, info = _lower_lm_cell(arch, shape_name, mesh)
         rec.update(info)
         if lowered is None:           # skipped cell
             rec["status"] = rec.get("status", "skipped")
@@ -349,7 +321,6 @@ def reanalyze(out_dir: str) -> int:
 
 
 LM_SHAPE_NAMES = ("train_4k", "prefill_32k", "decode_32k", "long_500k")
-CT_SHAPE_NAMES = ("P1", "P5", "P9", "P10")
 
 
 def force_host_devices(n: int = 512) -> None:
@@ -386,8 +357,6 @@ def main():
         for arch in list_archs():
             for shape in LM_SHAPE_NAMES:
                 cells.append((arch, shape))
-        for shape in CT_SHAPE_NAMES:
-            cells.append(("ct-backproject", shape))
     else:
         cells.append((args.arch, args.shape))
 

@@ -2,9 +2,9 @@
 
 Architecture (docs/ARCHITECTURE.md)
 -----------------------------------
-Since PR 2 every reconstruction entry point in this repo — untiled
+Every reconstruction entry point in this repo — untiled
 ``fdk_reconstruct``, this tiled engine, ``sart_step``, and the
-distributed driver — is a thin façade over the same three-stage core:
+reconstruction fleet — is a thin façade over the same three-stage core:
 
   1. **plan** — ``runtime.planner.plan_reconstruction`` builds a pure
      :class:`~repro.runtime.planner.ReconPlan`: the (i, j)-tile x Z-slab
@@ -13,9 +13,10 @@ distributed driver — is a thin façade over the same three-stage core:
      declarative ``KernelSpec`` registry (``core.variants.REGISTRY``),
      matrix-translation offsets, the projection-chunk schedule, and ALL
      option validation.
-  2. **compile** — ``runtime.executor.ProgramCache`` maps
-     ``(variant, call_shape, nb, dtype, interpret)`` keys to jitted
-     programs. Interior tiles share shapes, so a plan with hundreds of
+  2. **compile** — ``runtime.executor.ProgramCache.program`` maps
+     ``(variant, call_shape, nb, dtype, interpret, options, grid, rb)``
+     keys to jitted programs that take the step's box origin as a call
+     argument. Interior tiles share shapes, so a plan with hundreds of
      steps compiles a handful of programs; repeated ``reconstruct``
      calls hit the shared cache and never retrace.
   3. **execute** — ``runtime.executor.PlanExecutor`` walks the plan:
@@ -23,6 +24,8 @@ distributed driver — is a thin façade over the same three-stage core:
      filtering fused INTO the chunk loop (filtered projections are never
      materialized whole), and host placement is double-buffered so the
      device->host copy of tile ``n`` overlaps tile ``n+1``'s compute.
+     Across devices the same step programs run through
+     ``PlanExecutor.execute_fleet`` (``fdk_reconstruct(devices=)``).
 
 Why tiles (unchanged from PR 1)
 -------------------------------
@@ -58,8 +61,9 @@ Usage
     vol = fdk_reconstruct(projections, geom, tiling=(64, 64, geom.nz),
                           proj_batch=32)
 
-    # cluster scale-out: same tiles, each reconstructed over the mesh
-    vol = eng.backproject_distributed(img_t, mats, mesh)
+    # scale-out: the same tile steps spread over every local device
+    vol = fdk_reconstruct(projections, geom, tiling=(64, 64, geom.nz),
+                          devices="all")
 """
 
 from __future__ import annotations
@@ -192,34 +196,3 @@ class TiledReconstructor:
         accumulator) and a jax array otherwise.
         """
         return self._executor.reconstruct(projections)
-
-    # ---- cluster composition (iFDK scale-out x tiles) --------------------
-
-    def backproject_distributed(self, img_t: jnp.ndarray, mats: jnp.ndarray,
-                                mesh, *, nb: Optional[int] = None,
-                                dist_variant: str = "scan",
-                                pipeline: Optional[str] = None):
-        """Compose tiles with the data/model/pod mesh of core.distributed.
-
-        Each (i, j)-tile (full Z — the mesh shards i/j, slabs stay whole)
-        runs the shard_map program with the tile origin as a call-time
-        argument: ONE cached program per distinct tile shape. Projection
-        batches follow the plan's chunk schedule (tail padded).
-        ``pipeline`` ("sync" | "async"; default: this engine's own
-        discipline) streams tile flushes through the
-        ``_AsyncFlushQueue`` flusher thread exactly like the local
-        executor. Returns vol_t (nx, ny, nz) on host.
-        """
-        nb = self.recon_plan.nb if nb is None else int(nb)
-        # the mesh program consumes exactly-nb batches: plan chunks at nb
-        plan = plan_reconstruction(
-            self.geom, self.variant, tile_shape=self.recon_plan.tile_shape,
-            nb=nb, proj_batch=nb, out="host",
-            interpret=self.recon_plan.interpret)
-        ex = PlanExecutor(
-            self.geom, plan, cache=self._executor.cache,
-            pipeline=self._executor.pipeline if pipeline is None
-            else pipeline,
-            pipeline_depth=self._executor.pipeline_depth)
-        return ex.execute_distributed(img_t, mats, mesh,
-                                      dist_variant=dist_variant)

@@ -3,41 +3,42 @@
 ``runtime.planner`` produces a pure :class:`~repro.runtime.planner.ReconPlan`;
 this module turns it into arrays:
 
-  * :class:`ProgramCache` — the **compile** stage. One jitted program per
-    ``(variant, call_shape, nb, dtype, interpret, options)`` key, shared
-    by the tiled, untiled, and distributed executors: interior tiles of
-    equal shape and repeated ``reconstruct`` calls reuse the same
-    program instead of retracing. The step-major schedule adds a second
-    key family: ``scan_program`` keys additionally carry the chunk-loop
-    shape ``(n_chunks, chunk_size)`` and map to a ``lax.scan``
-    MEGAPROGRAM that sweeps the whole projection-chunk axis on device.
+  * :class:`ProgramCache` — the **compile** stage. One builder,
+    :meth:`ProgramCache.program`, keyed ``(variant, call_shape, nb,
+    dtype, interpret, options, grid, rb)``: the kernel placed at a
+    traced box origin (``core.variants.at_origin``), under a
+    ``lax.scan`` over the projection-chunk axis when ``grid`` is set
+    (the step-major megaprogram) and a leading request ``vmap`` when
+    ``rb`` is. Interior tiles of equal shape, repeated ``reconstruct``
+    calls and a fleet's steps on any device share one program.
     Hits/misses are introspectable (``cache.stats()``), and a
     module-level default cache persists across executors so repeated
     façade calls stay warm.
 
-  * :class:`PlanExecutor` — the **execute** stage. The default
-    (``plan.schedule == "step"``) walk is STEP-MAJOR: for each tile
-    step, one scan megaprogram carries the tile accumulator across ALL
+  * :class:`PlanExecutor` — the **execute** stage. One step walk
+    (``_walk``) serves every single-process loop order. The default
+    (``plan.schedule == "step"``) is STEP-MAJOR: for each tile step,
+    one scan megaprogram carries the tile accumulator across ALL
     projection chunks device-resident and the result crosses to the
     host exactly once — O(vol) device->host volume traffic and one
     dispatch per step, vs the chunk-major O(n_chunks x vol) traffic and
     O(n_chunks x n_steps) dispatches. Chunk filtering is hoisted into a
     filter-once producer that feeds every step. ``schedule == "chunk"``
-    keeps the PR-2 chunk-major loop (kept as the parity oracle and for
-    workloads where the filtered projection set must stay chunk-bounded
-    on device), now with input-side double buffering: the next chunk's
-    filtering is dispatched before the current chunk's host flush, so
-    it overlaps under JAX's async dispatch. Host placement remains
-    output-side double-buffered in both orders: the ``np.asarray``
-    device->host copy of step ``n`` is issued only after step ``n+1``'s
-    programs have been dispatched. ``pipeline="async"`` upgrades the
-    host flush to a real stream in EVERY loop order — step-major,
-    chunk-major, and the distributed tile walk: a depth-bounded
-    :class:`_AsyncFlushQueue` flusher thread performs the
-    ``block_until_ready`` + host accumulate off the dispatch thread, so
-    unit N's device->host copy genuinely overlaps unit N+1's dispatch
-    (the serving layer, ``runtime/service.py``, runs this by default).
-    The executor can also be built straight from an autotuned winner:
+    walks the steps once per projection chunk (kept as the parity
+    oracle and for workloads where the filtered projection set must
+    stay chunk-bounded on device), with input-side double buffering:
+    the next chunk's filtering is dispatched before the current chunk's
+    programs. Step outputs land through one :class:`_Accumulator`:
+    host placement double-buffered (the ``np.asarray`` device->host
+    copy of step ``n`` is issued only after step ``n+1``'s program has
+    been dispatched), or with ``pipeline="async"`` a depth-bounded
+    :class:`_AsyncFlushQueue` flusher thread that performs the
+    ``block_until_ready`` + host accumulate off the dispatch thread
+    (the serving layer, ``runtime/service.py``, runs this by default);
+    device placement a donated in-place add. ``execute_fleet`` is the
+    one path across devices: per-device step queues with work stealing
+    and failover over the same step programs. The executor can also be
+    built straight from an autotuned winner:
     :meth:`PlanExecutor.from_config` consumes a
     ``runtime.autotune.TunedConfig`` (the measured per-hardware choice
     of schedule/pipeline/variant/tile/chunk knobs).
@@ -70,7 +71,7 @@ from repro.core import backproject as bp
 from repro.core.filtering import fdk_filter_chunk
 from repro.core.geometry import CTGeometry, projection_matrices
 from repro.core.tiling import (
-    TileSpec, make_tiles, pad_projection_batch, plan_proj_chunks,
+    TileSpec, pad_projection_batch, plan_proj_chunks,
 )
 from repro.core.variants import at_origin, get_spec
 from repro.runtime import telemetry
@@ -129,14 +130,47 @@ def _with_precision(fn, variant: str, dtype: str):
     return wrapped
 
 
-class ProgramCache:
-    """Keyed cache of jitted back-projection programs.
+def _over_grid(call: Callable, shape, n_chunks: int, jittable: bool):
+    """``call`` summed over the stacked chunk axis of its inputs: one
+    ``lax.scan`` carrying the accumulator on device, or for a
+    non-jittable kernel a python chunk loop with a donated accumulator."""
+    if jittable:
+        def scanned(img_s, mat_s, origin):
+            def body(acc, xs):
+                img_c, mat_c = xs
+                return acc + call(img_c, mat_c, origin), None
+            acc, _ = jax.lax.scan(
+                body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
+            return acc
+        return scanned
 
-    Kernel programs are keyed ``(variant, call_shape, nb, dtype,
-    interpret, options)``; the distributed executor stores its shard_map
-    programs under its own key family via :meth:`get_or_build`. The
-    cache is thread-safe and introspectable: ``stats()`` reports hits,
-    misses (== programs built), and the live key count.
+    def looped(img_s, mat_s, origin):
+        acc = None
+        for c in range(n_chunks):
+            part = call(img_s[c], mat_s[c], origin)
+            acc = part if acc is None else _acc_add(acc, part)
+        return acc
+    return looped
+
+
+def _over_lanes(call: Callable, rb: int, jittable: bool):
+    """``call`` over a leading request axis of ``img`` (``mat`` and
+    ``origin`` shared): a ``vmap``, or a stacked loop over lanes."""
+    if jittable:
+        return jax.vmap(call, in_axes=(0, None, None))
+    return lambda img_b, mat, origin: jnp.stack(
+        [call(img_b[r], mat, origin) for r in range(rb)])
+
+
+class ProgramCache:
+    """Keyed cache of jitted programs.
+
+    Every back-projection program comes from :meth:`program`, keyed
+    ``("kernel", variant, call_shape, nb, dtype, interpret, options,
+    grid, rb)``; :meth:`get_or_build` is the cache under it, which the
+    solvers' forward-projection programs use with keys of their own.
+    The cache is thread-safe and introspectable: ``stats()`` reports
+    hits, misses (== programs built), and the live key count.
     """
 
     def __init__(self):
@@ -164,45 +198,34 @@ class ProgramCache:
 
     def program(self, variant: str, call_shape: Tuple[int, int, int],
                 nb: int, dtype: str, interpret: bool,
-                options: Tuple = ()) -> Callable:
-        """Jitted ``prog(img_t_chunk, mats_chunk, origin=None) ->
-        vol_t(call_shape)``; ``origin`` places the call's box in the
-        volume (:func:`~repro.core.variants.at_origin`)."""
+                options: Tuple = (), *,
+                grid: Optional[Tuple[int, int]] = None,
+                rb: Optional[int] = None) -> Callable:
+        """Jitted ``prog(img, mat, origin=None) -> vol_t(call_shape)``.
+
+        ``origin`` is the call box's voxel origin ``(i0, j0, k0)``, a
+        (3,) float32 array placed inside the program
+        (:func:`~repro.core.variants.at_origin`); ``None`` traces the
+        kernel as it is, the untiled plan's one call. A traced origin
+        makes one program serve every same-shape step on any device, so
+        a fleet step and a single-device step share this key.
+
+        ``grid=(n_chunks, chunk_size)`` takes the STACKED chunk axes
+        ``(n_chunks, chunk_size, ...)`` and carries the call-shape
+        accumulator across them in one ``lax.scan``: the step-major
+        megaprogram, one host crossing per step. ``rb`` adds a leading
+        request axis to ``img`` (one ``vmap`` lane per request over the
+        shared ``mat``); a lane never reassociates its reduction, so
+        each lane is bit-identical to the ``rb=None`` program. Kernels
+        that read concrete matrix values at trace time
+        (``KernelSpec.jittable=False``) get a python loop over chunks
+        (with a donated accumulator) and lanes instead, not wrapped in
+        ``jax.jit``.
+        """
         key = ("kernel", variant, tuple(call_shape), int(nb), str(dtype),
-               bool(interpret), tuple(options))
-
-        def build():
-            spec = get_spec(variant)
-            opts = spec.resolve_options(
-                {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
-            shape = tuple(call_shape)
-            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
-            prog = lambda img, mat, origin=None: fn(  # noqa: E731
-                img, mat, shape, origin, **opts)
-            # non-jittable kernels (KernelSpec.jittable=False) inspect
-            # concrete values at trace time; cache them un-wrapped
-            return jax.jit(prog) if spec.jittable else prog
-
-        return self.get_or_build(key, build)
-
-    def batch_program(self, variant: str, call_shape: Tuple[int, int, int],
-                      nb: int, dtype: str, interpret: bool,
-                      options: Tuple = (), *, rb: int) -> Callable:
-        """rb-lane chunk-kernel program: ``prog(img_b, mats, origin=None)
-        -> vol_b((rb,) + call_shape)`` where ``img_b`` stacks rb filtered
-        projection chunks ``(rb, chunk, nw, nh)`` over ONE shared
-        matrix chunk.
-
-        The streaming service uses this to fold the SAME view chunk of
-        rb concurrent scan sessions (same bucket ⇒ same geometry, same
-        chunk grid, same rotation phase) with one dispatch. The leading
-        ``vmap`` axis never reassociates a lane's reduction, so every
-        session stays bit-identical to its solo fold — the same
-        argument as :meth:`batch_scan_program`, one chunk at a time.
-        Non-jittable kernels fall back to a stacked per-lane loop.
-        """
-        key = ("batch_kernel", variant, tuple(call_shape), int(nb),
-               str(dtype), bool(interpret), tuple(options), int(rb))
+               bool(interpret), tuple(options),
+               None if grid is None else tuple(int(g) for g in grid),
+               None if rb is None else int(rb))
 
         def build():
             spec = get_spec(variant)
@@ -211,175 +234,17 @@ class ProgramCache:
             shape = tuple(call_shape)
             fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
 
-            def lane(img, mat, origin):
+            def call(img, mat, origin):
                 return fn(img, mat, shape, origin, **opts)
-            if spec.jittable:
-                lanes = jax.vmap(lane, in_axes=(0, None, None))
 
-                def one(img_b, mat, origin=None):
-                    return lanes(img_b, mat, origin)
-                return jax.jit(one)
-            return lambda img_b, mat, origin=None: jnp.stack(
-                [lane(img_b[r], mat, origin) for r in range(int(rb))])
+            if grid is not None:
+                call = _over_grid(call, shape, int(grid[0]), spec.jittable)
+            if rb is not None:
+                call = _over_lanes(call, int(rb), spec.jittable)
 
-        return self.get_or_build(key, build)
-
-    def scan_program(self, variant: str, call_shape: Tuple[int, int, int],
-                     nb: int, dtype: str, interpret: bool,
-                     options: Tuple = (), *, n_chunks: int,
-                     chunk_size: int) -> Callable:
-        """Step-major megaprogram: ``prog(img_chunks, mat_chunks,
-        origin=None) -> vol_t(call_shape)`` where the inputs are the
-        STACKED chunk axes ``(n_chunks, chunk_size, ...)`` and
-        ``origin`` the step's box origin (see :meth:`program`).
-
-        One ``lax.scan`` carries the call-shape accumulator across all
-        projection chunks on device — the executor emits it to host once
-        per step instead of once per (step, chunk). The key gains the
-        chunk-loop shape, so interior tiles of equal shape still compile
-        exactly once per (variant, call_shape, chunk grid).
-        """
-        key = ("scan", variant, tuple(call_shape), int(nb), str(dtype),
-               bool(interpret), tuple(options), int(n_chunks),
-               int(chunk_size))
-
-        def build():
-            spec = get_spec(variant)
-            opts = spec.resolve_options(
-                {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
-            shape = tuple(call_shape)
-            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
-            if spec.jittable:
-                def prog(img_s, mat_s, origin=None):
-                    def body(acc, xs):
-                        img_c, mat_c = xs
-                        return acc + fn(img_c, mat_c, shape, origin,
-                                        **opts), None
-                    acc, _ = jax.lax.scan(
-                        body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
-                    return acc
-                return jax.jit(prog)
-
-            # non-jittable kernels (banded_pl reads concrete matrix
-            # values at trace time) cannot sit under lax.scan: fall back
-            # to a python chunk loop with a DONATED device accumulator —
-            # still device-resident, still one host crossing per step.
-            def prog(img_s, mat_s, origin=None):
-                acc = None
-                for c in range(int(n_chunks)):
-                    part = fn(img_s[c], mat_s[c], shape, origin, **opts)
-                    acc = part if acc is None else _acc_add(acc, part)
-                return acc
-            return prog
-
-        return self.get_or_build(key, build)
-
-    def batch_scan_program(self, variant: str,
-                           call_shape: Tuple[int, int, int],
-                           nb: int, dtype: str, interpret: bool,
-                           options: Tuple = (), *, n_chunks: int,
-                           chunk_size: int, rb: int) -> Callable:
-        """rb-batched step-major megaprogram: ``prog(img_b, mat_s,
-        origin=None) -> vol_b((rb,) + call_shape)`` where ``img_b``
-        stacks ``rb`` requests' scan grids ``(rb, n_chunks, chunk_size,
-        ...)`` and
-        ``mat_s`` is the SHARED chunk-stacked matrix grid (same-bucket
-        requests share the geometry, so one matrix stack serves all
-        lanes).
-
-        One leading ``vmap`` axis over projections + accumulators turns
-        k queued reconstructions into ONE dispatch of the same scanned
-        program — per-lane float-op order is untouched, so each lane is
-        bit-identical to the single-request scan program (asserted in
-        tests/test_batching.py). Non-jittable kernels (banded_pl) fall
-        back to a stacked python loop over lanes with the donated-carry
-        chunk walk preserved: still one executor call per step, the
-        dispatch amortization just stops at the program boundary.
-        """
-        key = ("batch_scan", variant, tuple(call_shape), int(nb),
-               str(dtype), bool(interpret), tuple(options), int(n_chunks),
-               int(chunk_size), int(rb))
-
-        def build():
-            spec = get_spec(variant)
-            opts = spec.resolve_options(
-                {**dict(options), "nb": int(nb), "interpret": bool(interpret)})
-            shape = tuple(call_shape)
-            fn = at_origin(spec, _with_precision(spec.fn, variant, dtype))
-            if spec.jittable:
-                def lane(img_s, mat_s, origin):
-                    def body(acc, xs):
-                        img_c, mat_c = xs
-                        return acc + fn(img_c, mat_c, shape, origin,
-                                        **opts), None
-                    acc, _ = jax.lax.scan(
-                        body, jnp.zeros(shape, jnp.float32), (img_s, mat_s))
-                    return acc
-                lanes = jax.vmap(lane, in_axes=(0, None, None))
-
-                def one(img_b, mat_s, origin=None):
-                    return lanes(img_b, mat_s, origin)
-                return jax.jit(one)
-
-            def prog(img_b, mat_s, origin=None):
-                lanes = []
-                for r in range(int(rb)):
-                    acc = None
-                    for c in range(int(n_chunks)):
-                        part = fn(img_b[r, c], mat_s[c], shape, origin,
-                                  **opts)
-                        acc = part if acc is None else _acc_add(acc, part)
-                    lanes.append(acc)
-                return jnp.stack(lanes)
-            return prog
-
-        return self.get_or_build(key, build)
-
-    def fleet_program(self, variant: str, call_shape: Tuple[int, int, int],
-                      nb: int, dtype: str, interpret: bool,
-                      options: Tuple = (), *, n_chunks: int,
-                      chunk_size: int) -> Callable:
-        """Fleet step program: ``prog(img_s, mat_s, origin) ->
-        vol_t(call_shape)`` — the scan megaprogram with the step origin
-        as a TRACED call-time argument (``core.distributed
-        .make_fleet_bp``), so one key serves every same-shape step on
-        every device: work stealing and failover never add a key.
-        """
-        key = ("fleet", variant, tuple(call_shape), int(nb), str(dtype),
-               bool(interpret), tuple(options), int(n_chunks),
-               int(chunk_size))
-
-        def build():
-            from repro.core.distributed import make_fleet_bp
-            return make_fleet_bp(
-                variant, tuple(call_shape), nb=int(nb),
-                n_chunks=int(n_chunks), chunk_size=int(chunk_size),
-                options=tuple(options), interpret=bool(interpret))
-
-        return self.get_or_build(key, build)
-
-    def batch_fleet_program(self, variant: str,
-                            call_shape: Tuple[int, int, int],
-                            nb: int, dtype: str, interpret: bool,
-                            options: Tuple = (), *, n_chunks: int,
-                            chunk_size: int, rb: int) -> Callable:
-        """rb-batched fleet step program: ``prog(img_b, mat_s, origin)
-        -> vol_b((rb,) + call_shape)`` — :meth:`fleet_program`'s
-        origin-traced scan with the leading request axis of
-        :meth:`batch_scan_program`, so a fleet drains k batched
-        requests' step schedule with one dispatch per (device, step)
-        and stealing/failover still never recompile."""
-        key = ("batch_fleet", variant, tuple(call_shape), int(nb),
-               str(dtype), bool(interpret), tuple(options), int(n_chunks),
-               int(chunk_size), int(rb))
-
-        def build():
-            from repro.core.distributed import make_fleet_bp
-            return make_fleet_bp(
-                variant, tuple(call_shape), nb=int(nb),
-                n_chunks=int(n_chunks), chunk_size=int(chunk_size),
-                options=tuple(options), interpret=bool(interpret),
-                rb=int(rb))
+            def prog(img, mat, origin=None):
+                return call(img, mat, origin)
+            return jax.jit(prog) if spec.jittable else prog
 
         return self.get_or_build(key, build)
 
@@ -447,30 +312,38 @@ def _stack_chunks(img_p: jnp.ndarray, mat_p: jnp.ndarray,
     return img_s, mat_s
 
 
+def _grid(sched: StepMajorSchedule) -> Tuple[int, int]:
+    """The scan grid ``(n_chunks, chunk_size)`` of a step-major
+    schedule: the ``grid`` of its step programs."""
+    return (sched.n_chunks, sched.chunk_size)
+
+
+def _to_native(vol_t):
+    """(nx, ny, nz) -> (nz, ny, nx): a free numpy view of a host volume
+    (it may exceed device memory, never round-trip it), else on device."""
+    if isinstance(vol_t, np.ndarray):
+        return np.transpose(vol_t, (2, 1, 0))
+    return bp.volume_to_native(vol_t)
+
+
 class _AsyncFlushQueue:
     """Depth-bounded device->host flush pipeline (the "real streams"
     seam): step N's accumulator flush overlaps step N+1's dispatch.
 
-    The executor enqueues one step's ``(volume slices, device piece)``
-    writes right after dispatching that step's program and moves on; a
-    single flusher thread dequeues in FIFO order, calls
+    The executor enqueues one step's ``(host volume, slices, device
+    piece)`` writes right after dispatching that step's program and
+    moves on; a single flusher thread dequeues in FIFO order, calls
     ``jax.block_until_ready`` — the ONLY place the pipeline blocks on
     the device — and accumulates the ``np.asarray`` copy into the host
     volume. ``depth`` bounds how many steps' device outputs may be live
     at once (double-buffered by default: the scanning step plus the
     flushing one); a full queue applies backpressure to the dispatcher.
-    Exactly one thread writes the host volume, and steps write disjoint
-    regions, so the result is bit-identical to the sequential flush.
-
-    Writes are ``(slices, device piece)`` pairs into the constructor's
-    volume, or ``(target volume, slices, piece)`` triples — the
-    rb-batched step walk flushes one step's output into rb DIFFERENT
-    per-request volumes through one queue, preserving the single-writer
-    / FIFO discipline across all of them.
+    Exactly one thread writes the host volumes, in the order the writes
+    were enqueued, so the result is bit-identical to the sequential
+    flush.
     """
 
-    def __init__(self, vol: Optional[np.ndarray], depth: int = 2):
-        self._vol = vol
+    def __init__(self, depth: int = 2):
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(depth)))
         self._error: Optional[BaseException] = None
         self._thread = threading.Thread(
@@ -485,9 +358,7 @@ class _AsyncFlushQueue:
                     return
                 if self._error is None:   # keep consuming after failure
                     with telemetry.span("flush", n_writes=len(writes)):
-                        for w in writes:
-                            tgt, sl, piece = (w if len(w) == 3
-                                              else (self._vol, w[0], w[1]))
+                        for tgt, sl, piece in writes:
                             piece = jax.block_until_ready(piece)
                             tgt[sl] += np.asarray(piece)
             except BaseException as exc:   # surfaced at put()/close()
@@ -508,6 +379,66 @@ class _AsyncFlushQueue:
         self._thread.join()
         if self._error is not None:
             raise self._error
+
+
+class _Accumulator:
+    """Where a single-process walk's step outputs land: ``n_vols``
+    volumes (one per request lane) in the plan's placement.
+
+    ``put(writes)`` takes one step's ``(lane, volume slices, device
+    piece)`` writes right after the step's program is dispatched;
+    ``close()`` finishes every pending add and returns the volumes
+    (transposed layout). Per voxel the adds happen in ``put`` order, so
+    the three disciplines give the same bits:
+
+      * host, ``pipeline="sync"``: a double buffer, step n-1's pieces
+        are added only after step n has been dispatched, so the copy
+        overlaps compute;
+      * host, ``pipeline="async"``: the :class:`_AsyncFlushQueue`
+        flusher thread does the adds;
+      * device: the donated :func:`_place_device_add`. A whole-volume
+        first write IS the volume (the untiled plan's one call): no
+        zero volume is made and nothing is added to one.
+    """
+
+    def __init__(self, ex: "PlanExecutor", n_vols: int = 1):
+        self._shape = tuple(ex.plan.vol_shape_xyz)
+        self._host = ex.plan.out == "host"
+        self._vols = [np.zeros(self._shape, np.float32) if self._host
+                      else None for _ in range(n_vols)]
+        self._flush = (_AsyncFlushQueue(depth=ex.pipeline_depth)
+                       if self._host and ex.pipeline == "async" else None)
+        self._pending = ()
+
+    def put(self, writes) -> None:
+        if not self._host:
+            for r, sl, piece in writes:
+                self._vols[r] = self._device_add(self._vols[r], sl, piece)
+        elif self._flush is not None:
+            self._flush.put(tuple((self._vols[r], sl, piece)
+                                  for r, sl, piece in writes))
+        else:
+            self._add_pending()
+            self._pending = writes
+
+    def _device_add(self, vol, sl, piece):
+        if vol is None:
+            if piece.shape == self._shape:
+                return piece
+            vol = jnp.zeros(self._shape, jnp.float32)
+        idx = jnp.asarray([s.start for s in sl], jnp.int32)
+        return _place_device_add(vol, piece, idx)
+
+    def _add_pending(self) -> None:
+        for r, sl, piece in self._pending:
+            self._vols[r][sl] += np.asarray(piece)
+        self._pending = ()
+
+    def close(self) -> list:
+        if self._flush is not None:
+            self._flush.close()
+        self._add_pending()
+        return self._vols
 
 
 def _to_device(x, **args):
@@ -574,6 +505,16 @@ class _FilteredChunkProducer:
 
     def drop(self, c: int) -> None:
         self._memo.pop(c, None)
+
+    def streamed(self):
+        """Chunk-major: yield each chunk's ``(img_c, mat_c)`` with the
+        next chunk's filtering dispatched first (it overlaps this
+        chunk's compute), releasing each chunk once consumed."""
+        for c in range(len(self._chunks)):
+            pair = self.get(c)
+            self.prefetch(c + 1)
+            yield pair
+            self.drop(c)
 
     def stacked(self, sched: StepMajorSchedule):
         """All chunks, filtered once each, as the scan grid stack."""
@@ -719,14 +660,14 @@ class PlanExecutor:
     step-major scanned device accumulators by default, the chunk-major
     PR-2 loop on request.
 
-    ``pipeline`` selects the step-major flush discipline: ``"sync"``
+    ``pipeline`` selects the host flush discipline: ``"sync"``
     (the PR-3 in-thread double buffer — flush step N-1 after
     dispatching step N) or ``"async"`` (a :class:`_AsyncFlushQueue`
     flusher thread: step N's device->host accumulator copy overlaps
     step N+1's scan dispatch, ``jax.block_until_ready`` only at
     dequeue). Async only changes WHEN host adds happen, never their
     FIFO order, so output is bit-identical; it engages on host-placed
-    step-major walks and is a no-op elsewhere. ``pipeline_depth``
+    single-process walks and is a no-op elsewhere. ``pipeline_depth``
     bounds the in-flight step outputs (2 = double buffered).
     """
 
@@ -751,8 +692,8 @@ class PlanExecutor:
             if plan.precision != "f32":
                 raise ValueError(
                     "fleet execution does not support the reduced-"
-                    "precision data path yet (the origin-traced fleet "
-                    "programs are f32-only); plan with precision='f32', "
+                    "precision data path yet (no fleet run has been "
+                    "checked against it); plan with precision='f32', "
                     f"got {plan.precision!r}")
         self.geom = geom
         self.plan = plan
@@ -782,56 +723,21 @@ class PlanExecutor:
 
     # ---- compile-stage access -------------------------------------------
 
-    def _program(self, variant: str, call_shape) -> Callable:
+    def _program(self, variant: str, call_shape, *,
+                 grid: Optional[Tuple[int, int]] = None,
+                 rb: Optional[int] = None) -> Callable:
         return self.cache.program(variant, call_shape, self.plan.nb,
                                   self._dtype, self.plan.interpret,
-                                  self.plan.options)
-
-    def _scan_program(self, variant: str, call_shape,
-                      sched: StepMajorSchedule) -> Callable:
-        return self.cache.scan_program(variant, call_shape, self.plan.nb,
-                                       self._dtype, self.plan.interpret,
-                                       self.plan.options,
-                                       n_chunks=sched.n_chunks,
-                                       chunk_size=sched.chunk_size)
-
-    def _fleet_program(self, variant: str, call_shape,
-                       sched: StepMajorSchedule) -> Callable:
-        return self.cache.fleet_program(variant, call_shape, self.plan.nb,
-                                        self._dtype, self.plan.interpret,
-                                        self.plan.options,
-                                        n_chunks=sched.n_chunks,
-                                        chunk_size=sched.chunk_size)
-
-    def _batch_scan_program(self, variant: str, call_shape,
-                            sched: StepMajorSchedule, rb: int) -> Callable:
-        return self.cache.batch_scan_program(
-            variant, call_shape, self.plan.nb, self._dtype,
-            self.plan.interpret, self.plan.options,
-            n_chunks=sched.n_chunks, chunk_size=sched.chunk_size, rb=rb)
-
-    def _batch_fleet_program(self, variant: str, call_shape,
-                             sched: StepMajorSchedule, rb: int) -> Callable:
-        return self.cache.batch_fleet_program(
-            variant, call_shape, self.plan.nb, self._dtype,
-            self.plan.interpret, self.plan.options,
-            n_chunks=sched.n_chunks, chunk_size=sched.chunk_size, rb=rb)
+                                  self.plan.options, grid=grid, rb=rb)
 
     def warm(self) -> Dict[str, int]:
-        """Compile every distinct program the plan needs; return stats."""
-        if self.fleet is not None:
-            # one origin-traced program per (variant, shape) serves the
-            # whole fleet; XLA specializes per device on first dispatch
-            sched = self.plan.step_major
-            for variant, shape in self.plan.program_keys:
-                self._fleet_program(variant, shape, sched)
-        elif self.plan.schedule == "step":
-            sched = self.plan.step_major
-            for variant, shape in self.plan.program_keys:
-                self._scan_program(variant, shape, sched)
-        else:
-            for variant, shape in self.plan.program_keys:
-                self._program(variant, shape)
+        """Compile every distinct program the plan needs; return stats.
+        A fleet runs the single-device step programs (XLA specializes
+        per device on first dispatch)."""
+        grid = (_grid(self.plan.step_major) if self.plan.schedule == "step"
+                else None)
+        for variant, shape in self.plan.program_keys:
+            self._program(variant, shape, grid=grid)
         return self.cache.stats()
 
     @property
@@ -848,20 +754,16 @@ class PlanExecutor:
         for plans that don't support request batching."""
         if rb < 2 or not self.supports_request_batching:
             return self.cache.stats()
-        sched = self.plan.step_major
+        grid = _grid(self.plan.step_major)
         for variant, shape in self.plan.program_keys:
-            if self.fleet is not None:
-                self._batch_fleet_program(variant, shape, sched, rb)
-            else:
-                self._batch_scan_program(variant, shape, sched, rb)
+            self._program(variant, shape, grid=grid, rb=rb)
         return self.cache.stats()
 
     # ---- execute-stage helpers ------------------------------------------
 
-    def _alloc(self):
-        shape = self.plan.vol_shape_xyz
-        return (np.zeros(shape, np.float32) if self.plan.out == "host"
-                else jnp.zeros(shape, jnp.float32))
+    def _host_volume(self) -> np.ndarray:
+        """A zero host volume for the fleet's disjoint step writes."""
+        return np.zeros(self.plan.vol_shape_xyz, np.float32)
 
     def _origin(self, step: PlanStep) -> Optional[jnp.ndarray]:
         """The step's box origin ``(i0, j0, k_off)`` for its program
@@ -899,12 +801,15 @@ class PlanExecutor:
         return tuple(((isl, jsl, slice(w.k0, w.k0 + w.nk)),
                       out[..., w.lo:w.hi]) for w in step.writes)
 
-    def _open_flush(self, vol) -> Optional[_AsyncFlushQueue]:
-        """The async flusher when this walk pipelines host flushes
-        (``pipeline="async"`` + host placement), else None."""
-        if self.pipeline == "async" and self.plan.out == "host":
-            return _AsyncFlushQueue(vol, depth=self.pipeline_depth)
-        return None
+    def _lane_writes(self, step: PlanStep, out: jnp.ndarray,
+                     rb: Optional[int] = None):
+        """``(lane, volume slices, device piece)`` writes of one step's
+        output; ``rb`` lanes along ``out``'s leading axis."""
+        if rb is None:
+            return tuple((0, sl, piece)
+                         for sl, piece in self._step_writes(step, out))
+        return tuple((r, sl, piece) for r in range(rb)
+                     for sl, piece in self._step_writes(step, out[r]))
 
     def _step_span(self, step: PlanStep, n_views: int, **extra):
         """Telemetry span for one step dispatch (the args are only
@@ -915,154 +820,36 @@ class PlanExecutor:
                    n_views=int(n_views), **extra)
         return sp
 
-    def _backproject_chunk(self, vol, img_c: jnp.ndarray,
-                           mat_c: jnp.ndarray,
-                           flush: Optional[_AsyncFlushQueue] = None):
-        """Chunk-major: accumulate ONE projection chunk, all steps.
+    def _walk(self, units, *, grid: Optional[Tuple[int, int]] = None,
+              rb: Optional[int] = None) -> list:
+        """The single-process step walk: every plan step dispatched once
+        per ``(img, mat)`` unit, its outputs through one
+        :class:`_Accumulator`. Returns the volumes (transposed layout),
+        one per request lane.
 
-        ``flush`` (an open :class:`_AsyncFlushQueue` spanning the whole
-        chunk loop) moves the host adds onto the flusher thread; enqueue
-        order equals the sequential flush order, and float addition is
-        performed in that same order, so output stays bit-identical.
+        Step-major (``grid`` set) has one unit, the stacked scan grid:
+        one scanned device-resident accumulator per step and one host
+        crossing, O(vol) device->host traffic and O(n_steps) dispatches.
+        Chunk-major has one unit per projection chunk, each step's
+        output added into the volume per chunk. ``rb`` stacks request
+        lanes on ``img``'s leading axis (step-major only).
         """
-        plan = self.plan
-        host = plan.out == "host"
-        pending = ()   # previous step's (slices, device piece) writes
-        n_views = int(img_c.shape[0])
-        for step in plan.steps:
-            prog = self._program(step.variant, step.call_shape)
-            with self._step_span(step, n_views, schedule="chunk"):
-                out = prog(img_c, mat_c, self._origin(step))
-            cur = self._step_writes(step, out)
-            if not host:
-                for (i_s, j_s, k_s), piece in cur:
-                    idx = jnp.asarray([i_s.start, j_s.start, k_s.start],
-                                      jnp.int32)
-                    vol = _place_device_add(vol, piece, idx)
-            elif flush is not None:
-                flush.put(cur)
-            else:
-                # double buffer: flush step n-1's device->host copies
-                # only after step n's programs are dispatched, so the
-                # copy overlaps compute (async dispatch)
-                for sl, piece in pending:
-                    vol[sl] += np.asarray(piece)
-                pending = cur
-        for sl, piece in pending:
-            vol[sl] += np.asarray(piece)
-        return vol
-
-    def _execute_step_major(self, vol, img_s: jnp.ndarray,
-                            mat_s: jnp.ndarray,
-                            sched: StepMajorSchedule):
-        """Step-major: per step, ONE scanned device-resident accumulator
-        across all chunks, ONE (double-buffered) host emission.
-
-        ``img_s``/``mat_s`` are the stacked scan grids ``(n_chunks,
-        chunk_size, ...)``. Total device->host volume traffic is O(vol)
-        — each voxel crosses once — and dispatches are O(n_steps).
-        Host flushes follow ``self.pipeline``: in-thread double buffer
-        (``"sync"``) or the :class:`_AsyncFlushQueue` flusher thread
-        (``"async"`` — the dispatcher never blocks on a copy).
-        """
-        plan = self.plan
-        host = plan.out == "host"
-        if host and self.pipeline == "async":
-            flush = _AsyncFlushQueue(vol, depth=self.pipeline_depth)
-            try:
-                for work in sched.steps:
-                    step = work.step
-                    prog = self._scan_program(step.variant, step.call_shape,
-                                              sched)
-                    with self._step_span(step, sched.n_chunks *
-                                         sched.chunk_size, schedule="step"):
-                        out = prog(img_s, mat_s, self._origin(step))
-                    flush.put(self._step_writes(step, out))
-            finally:
-                flush.close()
-            return vol
-        pending = ()
-        for work in sched.steps:
-            step = work.step
-            prog = self._scan_program(step.variant, step.call_shape, sched)
-            with self._step_span(step, sched.n_chunks * sched.chunk_size,
-                                 schedule="step"):
-                out = prog(img_s, mat_s, self._origin(step))
-            cur = self._step_writes(step, out)
-            if host:
-                for sl, piece in pending:
-                    vol[sl] += np.asarray(piece)
-                pending = cur
-            else:
-                for (i_s, j_s, k_s), piece in cur:
-                    idx = jnp.asarray([i_s.start, j_s.start, k_s.start],
-                                      jnp.int32)
-                    vol = _place_device_add(vol, piece, idx)
-        for sl, piece in pending:
-            vol[sl] += np.asarray(piece)
-        return vol
-
-    def _execute_step_major_batch(self, vols, img_b: jnp.ndarray,
-                                  mat_s: jnp.ndarray,
-                                  sched: StepMajorSchedule):
-        """rb-batched step-major walk: per step, ONE dispatch of the
-        vmapped scan megaprogram fills this step's box in ALL ``rb``
-        per-request volumes.
-
-        ``img_b`` stacks the rb requests' scan grids ``(rb, n_chunks,
-        chunk_size, ...)``; ``mat_s`` is shared (same bucket == same
-        geometry). Flush discipline mirrors :meth:`_execute_step_major`
-        exactly — async flusher thread or in-thread double buffer —
-        with each step's writes fanned out to the rb host volumes
-        (the flusher's 3-tuple ``(target, slices, piece)`` form), so
-        per-lane accumulation order equals the sequential walk and the
-        result is bit-identical to rb separate runs.
-        """
-        plan = self.plan
-        host = plan.out == "host"
-        rb = len(vols)
-
-        def fanout(step, out_b):
-            return tuple((vols[r], sl, piece)
-                         for r in range(rb)
-                         for sl, piece in self._step_writes(step, out_b[r]))
-
-        if host and self.pipeline == "async":
-            flush = _AsyncFlushQueue(None, depth=self.pipeline_depth)
-            try:
-                for work in sched.steps:
-                    step = work.step
-                    prog = self._batch_scan_program(
-                        step.variant, step.call_shape, sched, rb)
-                    with self._step_span(step, sched.n_chunks *
-                                         sched.chunk_size, schedule="step",
-                                         rb=rb):
-                        out = prog(img_b, mat_s, self._origin(step))
-                    flush.put(fanout(step, out))
-            finally:
-                flush.close()
-            return vols
-        pending = ()
-        for work in sched.steps:
-            step = work.step
-            prog = self._batch_scan_program(step.variant, step.call_shape,
-                                            sched, rb)
-            with self._step_span(step, sched.n_chunks * sched.chunk_size,
-                                 schedule="step", rb=rb):
-                out = prog(img_b, mat_s, self._origin(step))
-            if host:
-                for tgt, sl, piece in pending:
-                    tgt[sl] += np.asarray(piece)
-                pending = fanout(step, out)
-            else:
-                for r in range(rb):
-                    for (i_s, j_s, k_s), piece in self._step_writes(
-                            step, out[r]):
-                        idx = jnp.asarray(
-                            [i_s.start, j_s.start, k_s.start], jnp.int32)
-                        vols[r] = _place_device_add(vols[r], piece, idx)
-        for tgt, sl, piece in pending:
-            tgt[sl] += np.asarray(piece)
+        acc = _Accumulator(self, 1 if rb is None else rb)
+        schedule = "chunk" if grid is None else "step"
+        extra = {} if rb is None else {"rb": rb}
+        try:
+            for img, mat in units:
+                n_views = (grid[0] * grid[1] if grid is not None
+                           else img.shape[0])
+                for step in self.plan.steps:
+                    prog = self._program(step.variant, step.call_shape,
+                                         grid=grid, rb=rb)
+                    with self._step_span(step, n_views, schedule=schedule,
+                                         **extra):
+                        out = prog(img, mat, self._origin(step))
+                    acc.put(self._lane_writes(step, out, rb))
+        finally:
+            vols = acc.close()
         return vols
 
     def execute_fleet(self, vol, img_s: jnp.ndarray,
@@ -1076,12 +863,13 @@ class PlanExecutor:
         device whose queue holds work before any step program is
         dispatched (an idle spare pays nothing; one that steals copies
         lazily), and one dispatcher thread per device drains its queue
-        through the shared origin-traced fleet program
-        (``ProgramCache.fleet_program``), each step's origin put onto
-        the worker's device from the host. A copy off another chip
-        would wait behind that chip's queued steps, so once steps
-        dispatch no worker reads an input that must leave another chip
-        (``FleetReport.late_copies`` counts the exceptions). Step
+        through the single-device walk's origin-traced step program
+        (``ProgramCache.program`` with the scan grid), each step's
+        origin put onto the worker's device from the host. A copy off
+        another chip would wait behind that chip's queued steps, so
+        once steps dispatch no worker reads an input that must leave
+        another chip (``FleetReport.late_copies`` counts the
+        exceptions). Step
         outputs land in the host volume's disjoint boxes, so completion
         order is irrelevant and the result equals the single-device
         step-major walk.
@@ -1114,8 +902,9 @@ class PlanExecutor:
         adds under the lock).
         """
         cfg = fleet if fleet is not None else (self.fleet or FleetConfig())
-        vols = list(vol) if isinstance(vol, (list, tuple)) else None
-        rb = len(vols) if vols is not None else None
+        rb = len(vol) if isinstance(vol, (list, tuple)) else None
+        vols = list(vol) if rb is not None else [vol]
+        grid = _grid(sched)
         devices = cfg.resolve_devices()
         n_dev = len(devices)
         steps = tuple(w.step for w in sched.steps)
@@ -1195,12 +984,8 @@ class PlanExecutor:
                                     counts["late_copies"] += 1
                         img_d, mat_d = ((img_s, mat_s) if copy is None
                                         else _landed(copy, d))
-                    prog = (self._fleet_program(step.variant,
-                                                step.call_shape, sched)
-                            if rb is None else
-                            self._batch_fleet_program(step.variant,
-                                                      step.call_shape,
-                                                      sched, rb))
+                    prog = self._program(step.variant, step.call_shape,
+                                         grid=grid, rb=rb)
                     origin = jax.device_put(
                         np.asarray([step.i0, step.j0, step.k_off],
                                    np.float32), dev)
@@ -1233,11 +1018,9 @@ class PlanExecutor:
                 dur = time.perf_counter() - t0
                 # flush the step's disjoint writes; order across steps
                 # is irrelevant (disjoint boxes into a zeroed volume)
-                writes = ([(vol, sl, piece)
-                           for sl, piece in self._step_writes(step, out)]
-                          if rb is None else
-                          [(vols[r], sl, piece) for r in range(rb)
-                           for sl, piece in self._step_writes(step, out[r])])
+                writes = [(vols[r], sl, piece)
+                          for r, sl, piece in self._lane_writes(step, out,
+                                                                rb)]
                 with telemetry.span("fleet.flush_wait", device=d,
                                     step_index=idx):
                     flush_lock.acquire()
@@ -1317,36 +1100,11 @@ class PlanExecutor:
             sched = self._data_step_major(chunks)
             img_s, mat_s = _stack_chunks(img_p, mat_p, sched)
             if self.fleet is not None:
-                return self.execute_fleet(self._alloc(), img_s, mat_s,
-                                          sched)
-            if self._single_full_call() and plan.out == "device":
-                step = plan.steps[0]
-                prog = self._scan_program(step.variant, step.call_shape,
-                                          sched)
-                with self._step_span(step, sched.n_chunks *
-                                     sched.chunk_size, schedule="step"):
-                    return prog(img_s, mat_s)
-            return self._execute_step_major(self._alloc(), img_s, mat_s,
-                                            sched)
-        if self._single_full_call() and plan.out == "device":
-            step = plan.steps[0]
-            prog = self._program(step.variant, step.call_shape)
-            acc = None
-            for s0, s1 in chunks:
-                with self._step_span(step, int(s1 - s0), schedule="chunk"):
-                    part = prog(img_p[s0:s1], mat_p[s0:s1])
-                acc = part if acc is None else acc + part
-            return acc
-        vol = self._alloc()
-        flush = self._open_flush(vol)
-        try:
-            for s0, s1 in chunks:
-                vol = self._backproject_chunk(vol, img_p[s0:s1],
-                                              mat_p[s0:s1], flush=flush)
-        finally:
-            if flush is not None:
-                flush.close()
-        return vol
+                return self.execute_fleet(self._host_volume(), img_s,
+                                          mat_s, sched)
+            return self._walk([(img_s, mat_s)], grid=_grid(sched))[0]
+        return self._walk((img_p[s0:s1], mat_p[s0:s1])
+                          for s0, s1 in chunks)[0]
 
     def backproject_tile(self, img_t: jnp.ndarray, mats: jnp.ndarray,
                          tile: TileSpec) -> jnp.ndarray:
@@ -1360,7 +1118,7 @@ class PlanExecutor:
         if plan.schedule == "step":
             sched = self._data_step_major(chunks)
             img_s, mat_s = _stack_chunks(img_p, mat_p, sched)
-            return self._scan_program(name, tile.shape, sched)(
+            return self._program(name, tile.shape, grid=_grid(sched))(
                 img_s, mat_s, origin)
         prog = self._program(name, tile.shape)
         acc = None
@@ -1411,54 +1169,14 @@ class PlanExecutor:
         mat_p = _pad_mats(projection_matrices(self.geom),
                           plan.n_proj_padded)
         producer = _FilteredChunkProducer(self, projections, mat_p)
-        if plan.schedule == "step":
-            sched = plan.step_major
-            img_s, mat_s = producer.stacked(sched)
-            if self.fleet is not None:
-                vol = self.execute_fleet(self._alloc(), img_s, mat_s,
-                                         sched)
-                return np.transpose(vol, (2, 1, 0))
-            if self._single_full_call() and plan.out == "device":
-                step = plan.steps[0]
-                prog = self._scan_program(step.variant, step.call_shape,
-                                          sched)
-                with self._step_span(step, sched.n_chunks *
-                                     sched.chunk_size, schedule="step"):
-                    acc = prog(img_s, mat_s)
-                return bp.volume_to_native(acc)
-            vol = self._execute_step_major(self._alloc(), img_s, mat_s,
-                                           sched)
-        elif self._single_full_call() and plan.out == "device":
-            step = plan.steps[0]
-            prog = self._program(step.variant, step.call_shape)
-            acc = None
-            for c in range(len(plan.chunks)):
-                img_c, mat_c = producer.get(c)
-                producer.prefetch(c + 1)   # overlaps this chunk's compute
-                with self._step_span(step, int(img_c.shape[0]),
-                                     schedule="chunk"):
-                    part = prog(img_c, mat_c)
-                acc = part if acc is None else acc + part
-                producer.drop(c)
-            return bp.volume_to_native(acc)
-        else:
-            vol = self._alloc()
-            flush = self._open_flush(vol)
-            try:
-                for c in range(len(plan.chunks)):
-                    img_c, mat_c = producer.get(c)
-                    producer.prefetch(c + 1)  # overlaps this chunk's compute
-                    vol = self._backproject_chunk(vol, img_c, mat_c,
-                                                  flush=flush)
-                    producer.drop(c)
-            finally:
-                if flush is not None:
-                    flush.close()
-        if isinstance(vol, np.ndarray):
-            # out="host": the accumulator may exceed device memory —
-            # transpose is a free numpy view, never round-trip it
-            return np.transpose(vol, (2, 1, 0))
-        return bp.volume_to_native(vol)
+        if plan.schedule == "chunk":
+            return _to_native(self._walk(producer.streamed())[0])
+        sched = plan.step_major
+        img_s, mat_s = producer.stacked(sched)
+        if self.fleet is not None:
+            return _to_native(self.execute_fleet(self._host_volume(),
+                                                 img_s, mat_s, sched))
+        return _to_native(self._walk([(img_s, mat_s)], grid=_grid(sched))[0])
 
     def open_stream(self, *, max_pending_chunks: int = 2,
                     on_ready: Optional[Callable[[int], None]] = None
@@ -1528,81 +1246,11 @@ class PlanExecutor:
         img_b = jnp.stack(lanes)
         del lanes
         if self.fleet is not None:
-            vols = [self._alloc() for _ in range(k)]
+            vols = [self._host_volume() for _ in range(k)]
             self.execute_fleet(vols, img_b, mat_s, sched)
-            return [np.transpose(v, (2, 1, 0)) for v in vols]
-        if self._single_full_call() and plan.out == "device":
-            step = plan.steps[0]
-            prog = self._batch_scan_program(step.variant, step.call_shape,
-                                            sched, k)
-            with self._step_span(step, sched.n_chunks * sched.chunk_size,
-                                 schedule="step", rb=k):
-                acc = prog(img_b, mat_s)
-            return [bp.volume_to_native(acc[r]) for r in range(k)]
-        vols = self._execute_step_major_batch(
-            [self._alloc() for _ in range(k)], img_b, mat_s, sched)
-        if isinstance(vols[0], np.ndarray):
-            return [np.transpose(v, (2, 1, 0)) for v in vols]
-        return [bp.volume_to_native(v) for v in vols]
-
-    # ---- cluster composition (iFDK scale-out x tiles) --------------------
-
-    def execute_distributed(self, img_t: jnp.ndarray, mats: jnp.ndarray,
-                            mesh, *, dist_variant: str = "scan"):
-        """Compose (i, j)-tiles with the data/model/pod mesh.
-
-        Each full-Z tile is reconstructed by a shard_map program from
-        ``core.distributed.make_distributed_bp`` with the tile origin as
-        a call-time argument — ONE program per distinct tile shape,
-        cached in the shared ProgramCache, so interior tiles and
-        repeated calls reuse it. Projection chunks follow the plan's
-        schedule. ``pipeline="async"`` streams here too: tile N's
-        device->host copy (behind its ``block_until_ready``) runs on
-        the flusher thread while tile N+1's shard_map programs are
-        dispatched; tiles write disjoint regions of the zeroed volume,
-        so the flusher's accumulate equals the sequential assignment.
-        Returns vol_t (nx, ny, nz) on host.
-        """
-        from repro.core.distributed import make_distributed_bp
-
-        plan = self.plan
-        nb = plan.nb
-        img_p, mat_p = pad_projection_batch(img_t, mats, nb)
-        # the shard_map program consumes exactly-nb batches: chunk the
-        # ACTUAL padded extent by nb (any view count streams through)
-        _, _, chunks = plan_proj_chunks(img_p.shape[0], nb, nb)
-        nx, ny, nz = plan.vol_shape_xyz
-        ti, tj, _ = plan.tile_shape
-        vol = np.zeros((nx, ny, nz), np.float32)
-        flush = (_AsyncFlushQueue(vol, depth=self.pipeline_depth)
-                 if self.pipeline == "async" else None)
-        try:
-            for tile in make_tiles((nx, ny, nz), (ti, tj, nz)):
-                # geom and mesh are both hashable (frozen dataclass /
-                # jax Mesh): keying on their VALUES makes equal setups
-                # share the program and distinct geometries never
-                # collide
-                key = ("dist", dist_variant, tile.shape, nb, self.geom,
-                       mesh)
-                prog = self.cache.get_or_build(
-                    key, lambda shape=tile.shape: make_distributed_bp(
-                        self.geom, mesh, nb=nb, variant=dist_variant,
-                        vol_shape_xyz=shape)[0])
-                origin = jnp.asarray([tile.i0, tile.j0], jnp.float32)
-                acc = None
-                for s0, s1 in chunks:
-                    part = prog(img_p[s0:s1], mat_p[s0:s1], origin)
-                    acc = part if acc is None else acc + part
-                if flush is not None:
-                    # unpad on device (lazy slice); the zeroed volume
-                    # makes the flusher's += equal the assignment
-                    flush.put(((tile.slices, acc[:tile.ni, :tile.nj]),))
-                else:
-                    vol[tile.slices] = np.asarray(acc)[:tile.ni, :tile.nj]
-        finally:
-            if flush is not None:
-                flush.close()
-        return vol
+        else:
+            vols = self._walk([(img_b, mat_s)], grid=_grid(sched), rb=k)
+        return [_to_native(v) for v in vols]
 
 
 # --------------------------------------------------------------------------
@@ -1922,42 +1570,19 @@ class StreamingExecutor:
             self._finish()
 
     def _finish(self) -> None:
-        """Place every step accumulator into the volume — the same
-        placement primitives (and float-op order) as the offline
-        chunk-major walk, ending in one host add per write into the
-        zeroed volume."""
-        with telemetry.span("stream.tail", n_chunks=self._n_chunks):
-            self._finish_inner()
-
-    def _finish_inner(self) -> None:
+        """Place every step accumulator into the volume through the
+        offline walk's :class:`_Accumulator` (the same placement and
+        float-op order), one add per write into the zeroed volume."""
         ex = self._ex
         plan = self._plan
-        if plan.out == "device":
-            if ex._single_full_call():
-                vol_t = self._accs[0]
-            else:
-                vol_t = jnp.zeros(plan.vol_shape_xyz, jnp.float32)
-                for step, acc in zip(plan.steps, self._accs):
-                    for (i_s, j_s, k_s), piece in ex._step_writes(step, acc):
-                        idx = jnp.asarray(
-                            [i_s.start, j_s.start, k_s.start], jnp.int32)
-                        vol_t = _place_device_add(vol_t, piece, idx)
-            result = bp.volume_to_native(vol_t)
-        else:
-            vol = np.zeros(plan.vol_shape_xyz, np.float32)
-            flush = ex._open_flush(vol)
+        with telemetry.span("stream.tail", n_chunks=self._n_chunks):
+            acc = _Accumulator(ex)
             try:
-                for step, acc in zip(plan.steps, self._accs):
-                    writes = ex._step_writes(step, acc)
-                    if flush is not None:
-                        flush.put(writes)
-                    else:
-                        for sl, piece in writes:
-                            vol[sl] += np.asarray(piece)
+                for step, part in zip(plan.steps, self._accs):
+                    acc.put(ex._lane_writes(step, part))
             finally:
-                if flush is not None:
-                    flush.close()
-            result = np.transpose(vol, (2, 1, 0))
+                vol_t = acc.close()[0]
+            result = _to_native(vol_t)
         with self._cond:
             self._accs = [None] * len(plan.steps)
             self._result = result

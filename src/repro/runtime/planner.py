@@ -34,7 +34,7 @@ The plan owns every scheduling decision the paper ties performance to:
     vol); ``schedule="chunk"`` keeps the PR-2 chunk-major loop;
   * option validation, in ONE place, for every façade
     (``fdk_reconstruct``, ``sart_step``, ``TiledReconstructor``,
-    ``backproject_distributed``).
+    ``ReconService``).
 
 Because planning is pure, every scheduling invariant is unit-testable
 without touching arrays (tests/test_planner.py).

@@ -980,7 +980,7 @@ class ReconService:
         warmed programs. Concurrent same-bucket sessions at the same
         rotation phase coalesce: the stream worker folds up to
         ``max_batch`` ready chunk-``c`` arrivals through ONE batched
-        dispatch (``ProgramCache.batch_program``), per-lane
+        dispatch (``ProgramCache.program`` with ``rb``), per-lane
         bit-identical to an unbatched session. ``max_pending_chunks``
         bounds the per-session arrival queue (``push`` blocks beyond
         it); ``priority > 0`` ships this session's chunks without
@@ -1085,10 +1085,8 @@ class ReconService:
             img_b = jnp.stack([img for img, _ in pairs])
             mat_c = pairs[0][1]        # same geometry -> same matrices
             for i, step in enumerate(plan.steps):
-                prog = self.cache.batch_program(
-                    step.variant, step.call_shape, plan.nb,
-                    ex._dtype, plan.interpret, plan.options,
-                    rb=len(cores))
+                prog = ex._program(step.variant, step.call_shape,
+                                   rb=len(cores))
                 out_b = prog(img_b, mat_c, ex._origin(step))
                 for r, core in enumerate(cores):
                     core.accept_part(i, out_b[r])

@@ -113,10 +113,9 @@ def test_zero_projections_give_zero_volume(small_geom, small_ct_data):
 
 def test_translated_matrices_equal_offset_volume(small_geom,
                                                  small_ct_data):
-    """Distribution correctness: back-projecting a sub-slab with
-    translated matrices equals the corresponding slab of the full
-    volume (core.distributed relies on this)."""
-    from repro.core.distributed import translate_matrices
+    """Tiling correctness: back-projecting a sub-slab with translated
+    matrices equals the corresponding slab of the full volume."""
+    from repro.core.tiling import translate_matrices
     img, mats = small_ct_data
     img_t = transpose_projections(img)
     full = bp_subline(img_t, mats, small_geom.volume_shape_xyz)

@@ -1,10 +1,12 @@
-"""Multi-device numerical tests (subprocess: 8 host devices).
+"""Multi-device numerical tests of the LM substrate (subprocess: 8 host
+devices).
 
-The dry-run proves the distributed programs COMPILE; these prove the
-shard_map back-projection and elastic resharding produce the right
-NUMBERS. They run in a subprocess because the device count must be fixed
-before jax initializes (the main test process keeps the default single
-device, per the harness contract).
+The dry-run proves the sharded programs COMPILE; these prove elastic
+resharding and the sharded train step produce the right NUMBERS. They
+run in a subprocess because the device count must be fixed before jax
+initializes (the main test process keeps the default single device, per
+the harness contract). The reconstruction fleet's multi-device tests are
+in test_fleet.py.
 """
 
 import json
@@ -19,49 +21,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json
 sys.path.insert(0, "src")
-import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.mesh import make_mesh
 
 out = {}
-
-# ---- distributed back-projection == single-device -----------------------
-from repro.core import (standard_geometry, projection_matrices,
-                        transpose_projections)
-from repro.core.backproject import bp_subline_symmetry_scan
-from repro.core.distributed import distributed_backproject
-
-geom = standard_geometry(n=16, n_det=24, n_proj=8)
-rng = np.random.RandomState(0)
-img = jnp.asarray(rng.rand(geom.n_proj, geom.nh, geom.nw).astype(np.float32))
-img_t = transpose_projections(img)
-mats = projection_matrices(geom)
-
-ref = bp_subline_symmetry_scan(img_t, mats, geom.volume_shape_xyz)
-
-mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
-# nb=6 does NOT divide n_proj=8: regression for the tail-batch padding
-# (used to be `assert n_proj % nb == 0`); 6 still divides over pod=2.
-vol = distributed_backproject(img_t, mats, geom, mesh, nb=6)
-err = float(jnp.abs(vol - ref).max()) / float(jnp.abs(ref).max())
-out["bp_rel_err"] = err
-
-# ---- tiled engine x mesh composition (5x7 tiles do not divide 16) --------
-from repro.runtime.engine import TiledReconstructor
-
-eng = TiledReconstructor(geom, tile_shape=(5, 7, geom.nz), nb=4)
-vol_t = eng.backproject_distributed(img_t, mats, mesh, nb=4)
-out["tiled_dist_rel_err"] = float(
-    jnp.abs(jnp.asarray(vol_t) - ref).max()) / float(jnp.abs(ref).max())
-
-# ---- async flush over the distributed tile walk (PR-4 follow-up) ---------
-# tiles write disjoint regions of the zeroed volume, so the flusher
-# thread's accumulate must equal the sequential assignment bit-for-bit
-vol_async = eng.backproject_distributed(img_t, mats, mesh, nb=4,
-                                        pipeline="async")
-out["tiled_dist_async_equal"] = bool(
-    np.array_equal(np.asarray(vol_t), np.asarray(vol_async)))
 
 # ---- elastic resharding roundtrip ----------------------------------------
 from repro.launch import sharding as shd
@@ -121,24 +84,6 @@ def multidevice_results():
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("RESULT:")][-1]
     return json.loads(line[len("RESULT:"):])
-
-
-def test_distributed_bp_matches_single_device(multidevice_results):
-    assert multidevice_results["bp_rel_err"] < 1e-5
-
-
-def test_tiled_engine_composes_with_mesh(multidevice_results):
-    """(i, j)-tiles reconstructed THROUGH the pod/data/model shard_map
-    program (make_distributed_bp(vol_shape_xyz=, origin=)) must match the
-    single-device reference — including the per-tile unpad slice."""
-    assert multidevice_results["tiled_dist_rel_err"] < 1e-5
-
-
-def test_distributed_async_flush_bit_identical(multidevice_results):
-    """execute_distributed(pipeline="async") streams tile flushes
-    through the _AsyncFlushQueue thread; disjoint tile writes into the
-    zeroed volume keep it bit-identical to the sequential walk."""
-    assert multidevice_results["tiled_dist_async_equal"]
 
 
 def test_elastic_reshard_roundtrip(multidevice_results):

@@ -79,6 +79,30 @@ out["fleet_devices"] = rep.n_devices
 out["fleet_steps"] = rep.n_steps
 out["fleet_steps_covered"] = int(sum(rep.steps_by_device))
 
+# ---- a tiling that does not divide the volume: (5, 7, nz) boxes ---------
+def fleet_equals_step_walk(geom, plan, run):
+    single = np.asarray(run(PlanExecutor(geom, plan)))
+    fleet = np.asarray(run(PlanExecutor(geom, plan, fleet=FleetConfig())))
+    return bool(np.array_equal(single, fleet))
+
+odd = _build_plan(geom, "algorithm1_mp", **{**kw, "tiling": (5, 7, geom.nz)})
+out["odd_tiles_steps"] = len(odd.steps)
+out["odd_tiles_bit_identical"] = fleet_equals_step_walk(
+    geom, odd, lambda ex: ex.reconstruct(projs))
+
+# ---- n_proj % nb != 0: 6 views in batches of 5 (the tail padded) ---------
+from repro.core import projection_matrices, transpose_projections
+geom6 = standard_geometry(n=16, n_det=24, n_proj=6)
+img6 = transpose_projections(jnp.asarray(
+    rng.rand(6, geom6.nh, geom6.nw).astype(np.float32)))
+mats6 = projection_matrices(geom6)
+plan6 = _build_plan(geom6, "algorithm1_mp", nb=5, interpret=True,
+                    tiling=(8, 8, geom6.nz), memory_budget=None,
+                    proj_batch=None, out="host", schedule="step")
+out["tail_n_proj_padded"] = plan6.n_proj_padded
+out["tail_bit_identical"] = fleet_equals_step_walk(
+    geom6, plan6, lambda ex: ex.backproject(img6, mats6))
+
 # ---- failover: device 3's steps forcibly failed --------------------------
 def fail_dev3(device, step):
     if device == 3:
@@ -184,7 +208,7 @@ def cell_rmse():
 # called before it
 puts, calls = [], []
 keep_put, keep_fleet = jax.device_put, PlanExecutor.execute_fleet
-keep_prog = PlanExecutor._fleet_program
+keep_prog = PlanExecutor._program
 in_fleet = threading.Event()
 
 def logged_put(x, device=None, *a, **k):
@@ -213,14 +237,14 @@ def logged_prog(self, *a, **k):
     return call
 
 jax.device_put, PlanExecutor.execute_fleet = logged_put, logged_fleet
-PlanExecutor._fleet_program = logged_prog
+PlanExecutor._program = logged_prog
 try:
     with telemetry.tracing():
         out["cell_rel_rmse"] = cell_rmse()
         evs = telemetry.events()
 finally:
     jax.device_put, PlanExecutor.execute_fleet = keep_put, keep_fleet
-    PlanExecutor._fleet_program = keep_prog
+    PlanExecutor._program = keep_prog
 out["cell_puts"] = puts
 out["cell_late_copies"] = late
 out["cell_spans"] = {name: sorted(e["args"].get("device", -1) for e in evs
@@ -272,6 +296,22 @@ def test_fleet_matches_single_device(fleet_results):
     assert fleet_results["fleet_rel_err"] < 1e-5
     assert fleet_results["fleet_steps_covered"] == \
         fleet_results["fleet_steps"]
+
+
+def test_fleet_on_tiles_that_do_not_divide_the_volume(fleet_results):
+    """(5, 7, nz) boxes leave narrower edge steps: the fleet's volume
+    equals the single-device step walk's bit for bit (one step program
+    per box shape, the same on every device)."""
+    assert fleet_results["odd_tiles_steps"] == 7 * 5   # 32/5 x 32/7
+    assert fleet_results["odd_tiles_bit_identical"]
+
+
+def test_fleet_with_a_padded_tail_batch(fleet_results):
+    """6 views in batches of nb=5: the tail is padded with zero views to
+    10, and the fleet's ``backproject`` equals the single-device step
+    walk bit for bit."""
+    assert fleet_results["tail_n_proj_padded"] == 10
+    assert fleet_results["tail_bit_identical"]
 
 
 def test_fleet_failover_bit_identical(fleet_results):

@@ -9,7 +9,10 @@ Covers the schedule-inversion seams:
     ALL registered variants, including non-divisible tail chunks and
     both accumulator placements;
   * ProgramCache under the chunk-loop key — interior tiles of equal
-    shape compile exactly once per (variant, call_shape, chunk grid);
+    shape compile exactly once per (variant, call_shape, chunk grid),
+    and a fleet step runs the single-device step's program;
+  * the accumulator — host double buffer, host flusher thread and
+    device in-place add each place every step's output bit for bit;
   * the filtered-chunk producer — filtering runs once per chunk no
     matter how many steps consume it, in both schedules;
   * proj_loop — planner resolution per variant and fused-kernel parity
@@ -25,7 +28,9 @@ from repro.core import (fdk_reconstruct, projection_matrices,
                         standard_geometry, transpose_projections)
 from repro.core import backproject as bp
 from repro.core.variants import REGISTRY, VARIANTS, get_spec
-from repro.runtime.executor import PlanExecutor, ProgramCache
+from repro.runtime.executor import (FleetConfig, PlanExecutor, ProgramCache,
+                                    _FilteredChunkProducer, _grid,
+                                    _pad_mats)
 from repro.runtime.planner import (build_step_major, plan_reconstruction)
 
 from conftest import rel_rmse
@@ -172,6 +177,74 @@ def test_scan_key_distinct_from_kernel_key(setup):
     assert cache.stats()["programs"] == 1
     PlanExecutor(geom, plan3, cache=cache).backproject(img_t, mats)
     assert cache.stats()["programs"] == 2  # different (n_chunks, size)
+
+
+def test_fleet_and_single_device_steps_share_programs(setup):
+    """A fleet step and a single-device step of one plan are the same
+    program under the same key: a fleet executor's ``warm()`` after a
+    single-device ``warm()`` builds nothing, batched programs too."""
+    geom, *_ = setup
+    cache = ProgramCache()
+    plan = plan_reconstruction(geom, "subline_batch_mp",
+                               tile_shape=(8, 8, 16), nb=2, proj_batch=4)
+    single = PlanExecutor(geom, plan, cache=cache)
+    single.warm()
+    single.warm_batch(2)
+    misses = cache.stats()["misses"]
+    assert misses == 2
+    fleet = PlanExecutor(geom, plan, cache=cache, fleet=FleetConfig())
+    fleet.warm()
+    fleet.warm_batch(2)
+    assert cache.stats()["misses"] == misses
+
+
+# ---- the accumulator: where the step outputs land -------------------------
+
+ACCUMULATORS = {"host-sync": ("host", "sync"),
+                "host-async": ("host", "async"),
+                "device": ("device", "sync")}
+
+
+def _steps_one_by_one(ex, projections):
+    """The tiled volume with no accumulator: each step's program called
+    on its own and its writes assigned into a zero host volume."""
+    plan = ex.plan
+    sched = plan.step_major
+    mat_p = _pad_mats(projection_matrices(ex.geom), plan.n_proj_padded)
+    img_s, mat_s = _FilteredChunkProducer(ex, projections,
+                                          mat_p).stacked(sched)
+    vol = np.zeros(plan.vol_shape_xyz, np.float32)
+    for step in plan.steps:
+        prog = ex._program(step.variant, step.call_shape, grid=_grid(sched))
+        out = prog(img_s, mat_s, ex._origin(step))
+        for sl, piece in ex._step_writes(step, out):
+            vol[sl] = np.asarray(piece)
+    return vol.transpose(2, 1, 0)
+
+
+@pytest.mark.parametrize("walk", ["reconstruct", "execute_batch"])
+@pytest.mark.parametrize("acc", sorted(ACCUMULATORS))
+def test_accumulator_places_every_step_bit_for_bit(setup, acc, walk):
+    """Each accumulator (host double buffer, host flusher thread,
+    device in-place add) lands every step's output exactly where its
+    program put it: the tiled volume, mirror-paired slabs and edge boxes
+    included, equals the step programs' outputs placed one at a time,
+    bit for bit, and the untiled call within the parity bar."""
+    geom, projs, *_ = setup
+    out, pipeline = ACCUMULATORS[acc]
+    plan = plan_reconstruction(geom, "algorithm1_mp", nb=2, proj_batch=4,
+                               tile_shape=(5, 7, 4), out=out)
+    assert any(step.paired for step in plan.steps)
+    ex = PlanExecutor(geom, plan, cache=ProgramCache(), pipeline=pipeline)
+    reqs = [projs, 0.5 * projs]
+    got = (ex.execute_batch(reqs) if walk == "execute_batch"
+           else [ex.reconstruct(projs)])
+    untiled = fdk_reconstruct(projs, geom, variant="algorithm1_mp", nb=2,
+                              proj_batch=4)
+    for p, vol in zip(reqs, got):
+        assert isinstance(vol, np.ndarray) == (out == "host")
+        assert np.array_equal(np.asarray(vol), _steps_one_by_one(ex, p))
+    assert rel_rmse(got[0], untiled) < BAR
 
 
 # ---- filter-once producer -------------------------------------------------

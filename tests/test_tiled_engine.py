@@ -162,7 +162,7 @@ def test_z_plan_covers_disjointly():
         assert (cover == 1).all(), (nz, tk)
 
 
-# ---- tail-batch padding (the distributed remainder fix) ------------------
+# ---- tail-batch padding ---------------------------------------------------
 
 def test_pad_projection_batch_is_exact(setup):
     """Zero-image / repeated-matrix padding contributes exactly nothing."""
@@ -175,30 +175,6 @@ def test_pad_projection_batch_is_exact(setup):
     # already-divisible input passes through untouched
     same_img, same_mat = pad_projection_batch(img_t, mats, 3)
     assert same_img is img_t and same_mat is mats
-
-
-def test_backproject_distributed_single_device_mesh(setup):
-    """Tile x mesh composition on the in-process 1-device mesh: exercises
-    make_distributed_bp(vol_shape_xyz=, origin=) and the per-tile unpad
-    (the 8-device version runs in test_distributed.py's subprocess)."""
-    from repro.launch.mesh import make_mesh
-    geom, img_t, mats, ref = setup
-    eng = TiledReconstructor(geom, tile_shape=(5, 7, geom.nz), nb=2)
-    mesh = make_mesh((1, 1), ("data", "model"))
-    vol = eng.backproject_distributed(img_t, mats, mesh, nb=2)
-    assert rel_rmse(vol, ref) < BAR
-
-
-def test_distributed_backproject_non_divisible_nproj(setup):
-    """Regression: n_proj % nb != 0 used to assert; now the tail batch is
-    padded. Single-device mesh keeps this in-process."""
-    from repro.core.distributed import distributed_backproject
-    from repro.launch.mesh import make_mesh
-    geom, img_t, mats, _ = setup
-    mesh = make_mesh((1, 1), ("data", "model"))
-    vol = distributed_backproject(img_t, mats, geom, mesh, nb=5)  # 6 % 5 != 0
-    ref = bp.bp_subline(img_t, mats, geom.volume_shape_xyz)
-    assert rel_rmse(vol, ref) < BAR
 
 
 # ---- auto-picker / working-set model -------------------------------------

@@ -219,9 +219,8 @@ def test_chip_smoke_step_program_fits_v5e(one_chip, variant, problem):
     }[(variant, problem)]
     opts = (("proj_loop", True),) if REGISTRY[variant].is_pallas else ()
     n_chunks = N_PROJ // chunk
-    prog = ProgramCache().scan_program(
-        variant, tile, 8, "float32", False, opts, n_chunks=n_chunks,
-        chunk_size=chunk)
+    prog = ProgramCache().program(
+        variant, tile, 8, "float32", False, opts, grid=(n_chunks, chunk))
     ok, used = _fits(prog.lower(
         _spec(one_chip, (n_chunks, chunk, det, det)),
         _spec(one_chip, (n_chunks, chunk, 3, 4))).compile())
@@ -229,15 +228,15 @@ def test_chip_smoke_step_program_fits_v5e(one_chip, variant, problem):
 
 
 def test_fleet_step_program_fits_v5e(one_chip):
-    """The fleet's shared step program at the P9 tile, with the step
-    origin a traced argument that reaches the sub-line kernel as
-    scalar-prefetched voxel indices."""
+    """The step program at the P9 tile, with the step origin a traced
+    argument that reaches the sub-line kernel as scalar-prefetched
+    voxel indices: the program every fleet step runs."""
     smoke = _chip_smoke()
     chunk = smoke.P9_PROJ_BATCH
     n_chunks = N_PROJ // chunk
-    prog = ProgramCache().fleet_program(
+    prog = ProgramCache().program(
         "subline_pl", smoke.P9_TILE, 8, "float32", False,
-        (("proj_loop", True),), n_chunks=n_chunks, chunk_size=chunk)
+        (("proj_loop", True),), grid=(n_chunks, chunk))
     compiled = prog.lower(
         _spec(one_chip, (n_chunks, chunk, 1024, 1024)),
         _spec(one_chip, (n_chunks, chunk, 3, 4)),
